@@ -59,8 +59,10 @@ def rows():
     # Out-of-VMEM streaming SpMM: the same in-VMEM operand through both
     # schedules (the slowdown gates the double-buffered pipeline's overlap),
     # then a giant operand whose resident plan the preflight rejects —
-    # streaming is the ONLY way it runs.  The giant row is runtime-capped
-    # to a single rep (bench-smoke budget).
+    # streaming is the ONLY way it runs.  The rejection is set by the RHS
+    # length (a million columns), so the row count is cut to 4096: interpret
+    # mode emulates one lane-gather pass per 128 columns of every 8-row index
+    # block, and at a million rows that took 13 minutes.  Single rep.
     sq = F.random_csr(4096, 4096, 8.0, seed=5)
     slabs_sq = F.csr_to_sell_slabs(sq, c=128, sigma=1024)
     xk = np.random.default_rng(2).standard_normal((4096, 8))
@@ -73,7 +75,7 @@ def rows():
            {"stream_slowdown": round(t_str / t_res, 3),
             "stream_vs_resident_throughput": round(t_res / t_str, 3)})
 
-    giant = F.random_csr(1 << 20, 1 << 20, 4.0, seed=9)
+    giant = F.random_csr(4096, 1 << 20, 4.0, seed=9)
     slabs_g = F.csr_to_sell_slabs(giant, c=512, sigma=4096)
     xg = np.random.default_rng(3).standard_normal((1 << 20, 8))
     try:
@@ -83,9 +85,9 @@ def rows():
         accepted = 0                 # the operand streaming exists for
     t_g = _time(lambda: ops.spmm(slabs_g, xg, vl=512, mode="stream"), reps=1)
     model = bench_roofline.spmm_stream_terms(
-        1 << 20, 1 << 20, giant.nnz, 8, c=512,
+        4096, 1 << 20, giant.nnz, 8, c=512,
         pad_factor=slabs_g.pad_factor)
-    yield ("spmm_1m_rows_k8_stream", t_g,
+    yield ("spmm_1m_cols_k8_stream", t_g,
            {"resident_plan_accepted": accepted,
             "pad_factor": round(slabs_g.pad_factor, 4),
             "modeled_overlap_speedup": round(model["overlap_speedup"], 3),

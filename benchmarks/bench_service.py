@@ -519,10 +519,21 @@ def bench_open_loop(rates=(10, 40, 160), n: int = 100, n_slots: int = 32,
     reg.register_graph("graph", graph)
     reg.register_fft("fft", n_fft)
 
+    # warm-up: Poisson arrivals form groups of any width up to the window,
+    # so compile every op at every pow2 group width (the service pads to
+    # one) — a compile inside a timed rung would set its p99, not the
+    # scheduler (each costs about a second in interpret mode)
     rng = np.random.default_rng(0)
-    warm = KernelService(reg, n_slots=n_slots)
-    _mixed_batch(rng, warm, csr, n_fft, min(n, 32), True)
-    warm.drain()
+    width = 1
+    while width <= n_slots:
+        warm = KernelService(reg, n_slots=4 * width)
+        for _ in range(width):
+            warm.submit("spmv", "mat", rng.standard_normal(csr.n_cols))
+            warm.submit("fft", "fft", rng.standard_normal((1, n_fft)))
+            warm.submit("pagerank", "graph", iters=2)
+            warm.submit("bfs", "graph", source=int(rng.integers(0, 64)))
+        warm.drain()
+        width *= 2
 
     def submit_one(svc, rng_l, i) -> bool:
         """One arrival from the mixed distribution; False = shed."""
@@ -595,7 +606,10 @@ def collect(loads=(8, 32, 100), requests: int | None = None,
 def main(argv=None) -> None:
     import jax
 
+    from repro.compile_cache import enable_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", default="BENCH_service.json",

@@ -100,7 +100,10 @@ def collect(device_counts=(1, 2, 4)) -> dict:
 def main(argv=None) -> None:
     import jax
 
+    from repro.compile_cache import enable_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", default="BENCH_sharded.json",

@@ -150,6 +150,9 @@ def main(argv=None) -> None:
                     help="attach Pallas interpret-mode timings to each "
                          "campaign (slow)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.campaign or args.check_claims:
         sys.exit(run_campaigns(args.campaign or [], args.sweeps_json,

@@ -12,6 +12,7 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import MachineParams, SDVMachine, VectorConfig
 from repro.core.sweep import latency_sweep, slowdown_tables
 from repro.core.traffic import TRACE_BUILDERS
@@ -62,5 +63,6 @@ def paper_numbers():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     kernels_demo()
     paper_numbers()

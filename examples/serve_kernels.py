@@ -6,7 +6,7 @@ Registers the cage10-like matrix, a random graph and an FFT plan, optionally
 warm-starts the tune cache from a stored campaign cube, serves a small mixed
 request batch through the micro-batching KernelService, and prints the cache
 and scheduler statistics — the registry -> tune -> submit lifecycle in one
-file.
+file.  x64 stays off, so on a TPU the operands are served in float32.
 """
 import argparse
 import os
@@ -15,12 +15,9 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.graphs.gen import random_graph
 from repro.service import KernelRegistry, KernelService, TuneCache
 from repro.sparse.formats import cage10_like
@@ -34,6 +31,7 @@ def main(argv=None) -> None:
                     help="campaign store to warm-start from (if present)")
     ap.add_argument("--requests", type=int, default=24)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cache = TuneCache(args.cache)
     if os.path.exists(args.sweeps):

@@ -10,6 +10,7 @@ import numpy as np
 import jax
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import Batcher, GenerationConfig, Request, ServeEngine
 
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--new-tokens", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.reduced_config(args.arch)
     params = M.init_params(jax.random.PRNGKey(0), cfg)

@@ -15,6 +15,7 @@ import tempfile
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.data import DataConfig
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, TrainLoopConfig, train_loop
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--width", type=int, default=256,
                     help="d_model override for the example model (CPU scale)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch) if args.full else configs.reduced_config(args.arch)
     if not args.full and args.width:

@@ -80,7 +80,7 @@ def _self_check_plans(out=sys.stdout) -> int:
     # rejects: prove the rejection -> acceptance pair on a synthetic
     # million-row operand (metadata only — nothing is packed or launched).
     giant = SlabMeta(
-        kind="matrix", c=8, widths=(8,), n_slices=(1 << 17,),
+        kind="matrix", c=512, widths=(8,), n_slices=(1 << 11,),
         n_rows=1 << 20, n_cols=1 << 20, val_dtype="float64",
         idx_dtype="int32")
     reject = plan_spmm_sell(giant, k=8, x_dtype="float64")

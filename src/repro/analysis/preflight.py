@@ -38,7 +38,13 @@ from repro.analysis.launchplan import (
     LaunchPlan,
     is_pow2,
 )
-from repro.sparse.formats import PAD, pow2_ceil
+from repro.sparse.formats import (
+    PAD,
+    SUBLANES,
+    k_tile_for,
+    pow2_ceil,
+    w_tile_for,
+)
 
 __all__ = [
     "SlabMeta",
@@ -52,10 +58,21 @@ __all__ = [
 ]
 
 _IDX_BYTES = 4                       # int32 column / adjacency indices
+_LANES = 128                         # lanes of a TPU vreg
 
 
 def _dtype_bytes(dtype: str) -> int:
     return int(np.dtype(dtype).itemsize)
+
+
+def _lane_table_bytes(n: int, k: int, c: int, itemsize: int) -> int:
+    """VMEM bytes of a (k, R, lanes) gather table over ``n`` entries
+    (:func:`repro.kernels.sell_core.lane_table`): R rows of
+    ``min(c, 128)`` entries, each (R, lanes) plane padded to whole
+    (8, 128) vreg tiles."""
+    lanes = min(max(int(c), 1), _LANES)
+    r = max(math.ceil(max(int(n), 1) / lanes), 1)
+    return int(k) * (SUBLANES * math.ceil(r / SUBLANES)) * _LANES * itemsize
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,19 +191,19 @@ def plan_spmm_sell(
         elif meta.val_dtype is not None and x_dtype != meta.val_dtype:
             violations.append(
                 f"RHS dtype {x_dtype} != slab value dtype {meta.val_dtype}")
-    k_tile = min(max(int(k_block), 1), pow2_ceil(max(k, 1)))
+    k_tile = k_tile_for(k, k_block)
     k_pad = k_tile * math.ceil(max(k, 1) / k_tile)
     xb = _dtype_bytes(x_dtype) if x_dtype is not None else vb
     blocks = []
     for i, (s, w) in enumerate(zip(meta.n_slices, meta.widths)):
-        w_eff = min(max(int(w_block), 1), w)
+        w_eff = w_tile_for(w, w_block)
         w_pad = w_eff * math.ceil(w / w_eff)
         grid = (s, k_pad // k_tile, w_pad // w_eff)
         footprint = (
             2 * w_eff * meta.c * (vb + _IDX_BYTES)   # double-buffered slab tile
-            + 2 * meta.n_cols * k_tile * xb          # pipelined RHS block pair
+            + 2 * _lane_table_bytes(meta.n_cols, k_tile, meta.c, xb)
             + 2 * meta.c * k_tile * vb               # pipelined output pair
-        )
+        )                                            # (RHS table pair above)
         if footprint > vmem_budget:
             violations.append(
                 f"bucket {i} (W={w}): per-cell footprint {footprint} B "
@@ -198,8 +215,8 @@ def plan_spmm_sell(
             blocks=(
                 ("cols", (1, w_eff, meta.c), meta.idx_dtype),
                 ("vals", (1, w_eff, meta.c), val_dtype),
-                ("x", (meta.n_cols, k_tile), x_dtype or val_dtype),
-                ("y", (1, meta.c, k_tile), val_dtype),
+                ("x", (k_tile, meta.n_cols), x_dtype or val_dtype),
+                ("y", (1, k_tile, meta.c), val_dtype),
             ),
             vmem_bytes=footprint,
         ))
@@ -305,18 +322,18 @@ def plan_spmm_sell_sharded(
         elif meta.val_dtype is not None and x_dtype != meta.val_dtype:
             violations.append(
                 f"RHS dtype {x_dtype} != slab value dtype {meta.val_dtype}")
-    k_tile = min(max(int(k_block), 1), pow2_ceil(max(k, 1)))
+    k_tile = k_tile_for(k, k_block)
     k_pad = k_tile * math.ceil(max(k, 1) / k_tile)
     xb = _dtype_bytes(x_dtype) if x_dtype is not None else vb
     blocks = []
     for i, (s, w) in enumerate(zip(meta.n_slices, meta.widths)):
         s_dev = math.ceil(max(s, 1) / nd)        # slices on the busiest shard
-        w_eff = min(max(int(w_block), 1), w)
+        w_eff = w_tile_for(w, w_block)
         w_pad = w_eff * math.ceil(w / w_eff)
         grid = (s_dev, k_pad // k_tile, w_pad // w_eff)
         footprint = (
             2 * w_eff * meta.c * (vb + _IDX_BYTES)   # double-buffered slab tile
-            + 2 * win * k_tile * xb                  # windowed RHS block pair
+            + 2 * _lane_table_bytes(win, k_tile, meta.c, xb)  # RHS window
             + 2 * meta.c * k_tile * vb               # pipelined output pair
         )
         if footprint > vmem_budget:
@@ -330,8 +347,8 @@ def plan_spmm_sell_sharded(
             blocks=(
                 ("cols", (1, w_eff, meta.c), meta.idx_dtype),
                 ("vals", (1, w_eff, meta.c), val_dtype),
-                ("x_window", (win, k_tile), x_dtype or val_dtype),
-                ("y", (1, meta.c, k_tile), val_dtype),
+                ("x_window", (k_tile, win), x_dtype or val_dtype),
+                ("y", (1, k_tile, meta.c), val_dtype),
             ),
             vmem_bytes=footprint,
         ))
@@ -397,20 +414,22 @@ def plan_spmm_sell_stream(
         elif meta.val_dtype is not None and x_dtype != meta.val_dtype:
             violations.append(
                 f"RHS dtype {x_dtype} != slab value dtype {meta.val_dtype}")
-    k_tile = min(max(int(k_block), 1), pow2_ceil(max(k, 1)))
+    k_tile = k_tile_for(k, k_block)
     k_pad = k_tile * math.ceil(max(k, 1) / k_tile)
     xb = _dtype_bytes(x_dtype) if x_dtype is not None else vb
-    ct = min(pow2_ceil(max(int(col_tile), 1)), pow2_ceil(max(meta.n_cols, 1)))
+    ct = max(min(pow2_ceil(max(int(col_tile), 1)),
+                 pow2_ceil(max(meta.n_cols, 1))),
+             min(meta.c, _LANES))             # at least one table chunk
     blocks = []
     for i, (s, w) in enumerate(zip(meta.n_slices, meta.widths)):
-        w_eff = min(max(int(w_block), 1), w)
+        w_eff = w_tile_for(w, w_block)
         w_pad = w_eff * math.ceil(w / w_eff)
         rt = min(max(int(row_tile), 1), max(s, 1))
         s_pad = rt * math.ceil(max(s, 1) / rt)
         grid = (s_pad // rt, k_pad // k_tile)
         footprint = (
             2 * w_eff * meta.c * (vb + _IDX_BYTES)   # slab tile pairs
-            + 2 * ct * k_tile * xb                   # RHS tile pair
+            + 2 * _lane_table_bytes(ct, k_tile, meta.c, xb)  # RHS tile pair
             + rt * meta.c * k_tile * vb              # accumulator
         )
         if footprint > vmem_budget:
@@ -425,8 +444,8 @@ def plan_spmm_sell_stream(
             blocks=(
                 ("cols_buf", (2, w_eff, meta.c), meta.idx_dtype),
                 ("vals_buf", (2, w_eff, meta.c), val_dtype),
-                ("x_buf", (2, ct, k_tile), x_dtype or val_dtype),
-                ("y_acc", (rt, meta.c, k_tile), val_dtype),
+                ("x_buf", (2, k_tile, ct), x_dtype or val_dtype),
+                ("y_acc", (rt, k_tile, meta.c), val_dtype),
             ),
             vmem_bytes=footprint,
         ))
@@ -442,24 +461,24 @@ def _plan_node_step(
     meta: SlabMeta,
     k: int,
     state_dtype: str,
-    resident_bytes: int,
     vmem_budget: int,
 ) -> LaunchPlan:
     """Shared plan for the ``bucketed_node_step`` drivers (BFS, PageRank):
-    per bucket one (1, C, W) adjacency tile (double-buffered), the whole
-    resident state, and a (1, C[, k]) output tile."""
+    per bucket one (1, W, C) adjacency tile, the whole (n + 1, k) state as
+    a lane table, and a (1, k, C) output tile — each double-buffered by
+    the pipeline.  The step's scalars sit in SMEM."""
     violations: list[str] = []
     if k < 1:
         violations.append(f"state stack must have k >= 1 columns, got {k}")
     _shared_slab_contracts(meta, violations)
     sb = _dtype_bytes(state_dtype)
+    table = _lane_table_bytes(meta.n_rows + 1, max(k, 1), meta.c, sb)
     blocks = []
     for i, (s, w) in enumerate(zip(meta.n_slices, meta.widths)):
-        out_tile = (1, meta.c) if k == 1 else (1, meta.c, k)
         footprint = (
-            2 * meta.c * w * _IDX_BYTES              # double-buffered adj tile
-            + resident_bytes                         # state columns, whole
-            + meta.c * max(k, 1) * sb                # output tile
+            2 * meta.c * w * _IDX_BYTES              # adjacency tile pair
+            + 2 * table                              # state table pair
+            + 2 * meta.c * max(k, 1) * sb            # output tile pair
         )
         if footprint > vmem_budget:
             violations.append(
@@ -469,10 +488,9 @@ def _plan_node_step(
             label=f"bucket{i}[W={w}]",
             grid=(s,),
             blocks=(
-                ("adj", (1, meta.c, w), meta.idx_dtype),
-                ("state", (meta.n_rows + 1,) if k == 1
-                 else (meta.n_rows + 1, k), state_dtype),
-                ("out", out_tile, state_dtype),
+                ("adj", (1, w, meta.c), meta.idx_dtype),
+                ("state", (max(k, 1), meta.n_rows + 1), state_dtype),
+                ("out", (1, max(k, 1), meta.c), state_dtype),
             ),
             vmem_bytes=footprint,
         ))
@@ -489,14 +507,9 @@ def plan_bfs_sell(
     *,
     vmem_budget: int = VMEM_BUDGET_BYTES,
 ) -> LaunchPlan:
-    """Plan one ``bfs_step_sell`` level for k stacked sources.
-
-    Resident state: the (n + 1[, k]) int32 distance columns plus the (1,)
-    level scalar.
-    """
-    resident = (meta.n_rows + 1) * max(k, 1) * 4 + 4
-    return _plan_node_step(
-        "bfs_sell", meta, k, "int32", resident, vmem_budget)
+    """Plan one ``bfs_step_sell`` level for k stacked sources: the state
+    is the (n + 1, k) int32 distance columns."""
+    return _plan_node_step("bfs_sell", meta, k, "int32", vmem_budget)
 
 
 def plan_pagerank_sell(
@@ -506,15 +519,9 @@ def plan_pagerank_sell(
     *,
     vmem_budget: int = VMEM_BUDGET_BYTES,
 ) -> LaunchPlan:
-    """Plan one ``pagerank_step_sell`` power step for k stacked configs.
-
-    Resident state: the (n + 1[, k]) contribution columns plus the (3[, k])
-    constants, in the rank dtype.
-    """
-    b = _dtype_bytes(dtype)
-    resident = ((meta.n_rows + 1) + 3) * max(k, 1) * b
-    return _plan_node_step(
-        "pagerank_sell", meta, k, dtype, resident, vmem_budget)
+    """Plan one ``pagerank_step_sell`` power step for k stacked configs:
+    the state is the (n + 1, k) contribution columns in the rank dtype."""
+    return _plan_node_step("pagerank_sell", meta, k, dtype, vmem_budget)
 
 
 def plan_fft_stockham(
@@ -527,8 +534,11 @@ def plan_fft_stockham(
 ) -> LaunchPlan:
     """Plan ``fft_stockham`` for a (batch, n) split-plane signal block.
 
-    One grid cell holds four (b_block, n) planes (re/im in and out) plus the
-    whole (stages, n/2) x 2 twiddle table.
+    One grid cell holds the re/im input and output blocks (each
+    double-buffered), the two-deep re/im ping-pong scratch, and the
+    (stages, n/2) re/im twiddle tables (double-buffered): twelve signal
+    planes of (n/lanes, b_block, lanes) chunks plus four twiddle planes,
+    each padded to whole (8, 128) vreg tiles.
     """
     violations: list[str] = []
     if n < 2 or not is_pow2(n):
@@ -540,7 +550,12 @@ def plan_fft_stockham(
     b = _dtype_bytes(dtype)
     bb = max(int(b_block), 1)
     stages = int(math.log2(n)) if n >= 2 and is_pow2(n) else 0
-    footprint = 4 * bb * n * b + 2 * stages * (n // 2) * b
+    lanes = max(min(_LANES, n // 2), 1)
+    chunks = max(n // lanes, 1)
+    plane = chunks * SUBLANES * math.ceil(bb / SUBLANES) * _LANES * b
+    twiddle = stages * SUBLANES * math.ceil(
+        max(chunks // 2, 1) / SUBLANES) * _LANES * b
+    footprint = 12 * plane + 4 * twiddle
     if footprint > vmem_budget:
         violations.append(
             f"per-cell footprint {footprint} B exceeds VMEM budget "

@@ -1,23 +1,15 @@
-"""Version-adaptive jax compatibility layer.
+"""jax mesh / sharding compatibility layer.
 
-Everything in the repo that touches a version-sensitive jax surface — mesh
-construction, current-mesh discovery, mesh activation, sharding
-constraints, ``shard_map``/``pjit`` — goes through this package.  See
-``jaxshim`` for the low-level wrappers and ``meshctx`` for the explicit
+Everything in the repo that touches a mesh- or sharding-sensitive jax
+surface — mesh construction, current-mesh discovery, mesh activation,
+sharding constraints, ``shard_map``/``pjit`` — goes through this package.
+See ``jaxshim`` for the low-level wrappers and ``meshctx`` for the explicit
 :class:`MeshContext` threading that replaced the seed's implicit
 ``get_abstract_mesh()`` global lookups.
 
-Supported: jax 0.4.x (the resource-env era, including the pinned 0.4.37)
-through the 0.6+ ``set_mesh``/``AxisType`` era.  Feature detection is by
-attribute probing, never by version comparison.
+Supported: the one installed jax, 0.9 (pinned in ``requirements.txt``).
 """
 from repro.compat.jaxshim import (
-    HAS_AXIS_TYPE,
-    HAS_GET_ABSTRACT_MESH,
-    HAS_MAKE_MESH,
-    HAS_SET_MESH,
-    HAS_USE_MESH,
-    JAX_VERSION,
     ambient_mesh,
     cost_analysis,
     make_mesh,
@@ -35,12 +27,6 @@ from repro.compat.meshctx import (
 )
 
 __all__ = [
-    "JAX_VERSION",
-    "HAS_AXIS_TYPE",
-    "HAS_GET_ABSTRACT_MESH",
-    "HAS_SET_MESH",
-    "HAS_USE_MESH",
-    "HAS_MAKE_MESH",
     "make_mesh",
     "ambient_mesh",
     "native_mesh_scope",

@@ -1,22 +1,17 @@
-"""Version-adaptive wrappers over jax's mesh / sharding API surface.
+"""Wrappers over jax's mesh / sharding API surface (jax 0.9).
 
-The repo targets the jax people actually have installed, which spans the
-0.4.x "resource env" era (``Mesh`` as a context manager, no
-``get_abstract_mesh``), the 0.5.x ``jax.sharding.use_mesh`` era, and the
-0.6+ ``jax.set_mesh`` / ``AxisType`` era.  Every version-sensitive call in
-the codebase funnels through this module — one choke point instead of
-scattered ``jax.sharding.*`` lookups that AttributeError on the wrong
-version:
+Every mesh- or sharding-sensitive call in the codebase funnels through this
+module — one choke point instead of scattered ``jax.sharding.*`` lookups,
+so a future jax that moves one of these surfaces changes one file:
 
-* :func:`make_mesh` — mesh construction, with ``axis_types`` only where
-  the installed jax supports it;
-* :func:`ambient_mesh` — jax's own notion of the currently active mesh
-  (abstract mesh on new jax, the legacy resource-env physical mesh on old);
-* :func:`native_mesh_scope` — activate a mesh the way this jax wants it
-  activated (``set_mesh`` / ``use_mesh`` / legacy ``with mesh:``);
+* :func:`make_mesh` — mesh construction with ``Auto`` axis types (the
+  repo's sharding is constraint-driven);
+* :func:`ambient_mesh` — jax's own currently active (abstract) mesh;
+* :func:`native_mesh_scope` — activate a mesh with ``jax.set_mesh``;
 * :func:`with_sharding_constraint` — constraint application that degrades
   to a no-op when no mesh is reachable instead of raising;
-* :func:`shard_map` / :func:`pjit` — stable entry points for the moved
+* :func:`cost_analysis` — ``Compiled.cost_analysis()`` as a dict;
+* :func:`shard_map` / :func:`pjit` — stable entry points for the
   transforms.
 
 Higher-level mesh threading (the explicit :class:`~repro.compat.meshctx.\
@@ -32,144 +27,48 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
-def _version_tuple(v: str) -> tuple[int, ...]:
-    parts = []
-    for tok in v.split(".")[:3]:
-        num = ""
-        for ch in tok:
-            if not ch.isdigit():
-                break
-            num += ch
-        parts.append(int(num) if num else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple[int, ...] = _version_tuple(jax.__version__)
-
-#: feature probes — attribute checks, not version comparisons, so backports
-#: and future renames behave correctly
-HAS_AXIS_TYPE: bool = hasattr(jax.sharding, "AxisType")
-HAS_GET_ABSTRACT_MESH: bool = hasattr(jax.sharding, "get_abstract_mesh")
-HAS_SET_MESH: bool = hasattr(jax, "set_mesh")
-HAS_USE_MESH: bool = hasattr(jax.sharding, "use_mesh")
-HAS_MAKE_MESH: bool = hasattr(jax, "make_mesh")
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction
-# ---------------------------------------------------------------------------
-
-
 def make_mesh(
     axis_shapes: Sequence[int],
     axis_names: Sequence[str],
     *,
     devices: Sequence[Any] | None = None,
 ) -> Mesh:
-    """Build a device mesh on any supported jax.
-
-    Uses ``axis_types=Auto`` where the installed jax understands it (the
-    repo's sharding is constraint-driven, i.e. Auto everywhere) and plain
-    ``jax.make_mesh`` / ``mesh_utils`` otherwise.
-    """
-    shapes = tuple(int(s) for s in axis_shapes)
+    """Build a device mesh with ``Auto`` axis types."""
     names = tuple(axis_names)
     kwargs: dict[str, Any] = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if HAS_MAKE_MESH:
-        if HAS_AXIS_TYPE:
-            try:
-                return jax.make_mesh(
-                    shapes,
-                    names,
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(names),
-                    **kwargs,
-                )
-            except TypeError:
-                pass  # make_mesh exists but predates axis_types
-        return jax.make_mesh(shapes, names, **kwargs)
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(shapes, devices=devices)
-    return Mesh(devs, names)
-
-
-# ---------------------------------------------------------------------------
-# Current-mesh discovery
-# ---------------------------------------------------------------------------
-
-
-def _resource_env():
-    """The legacy thread-local resource env (0.4.x), or None."""
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        return _mesh_lib.thread_resources.env
-    except Exception:
-        try:  # pre-0.4 spelling
-            from jax.experimental.maps import thread_resources
-
-            return thread_resources.env
-        except Exception:
-            return None
+    return jax.make_mesh(
+        tuple(int(s) for s in axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names), **kwargs)
 
 
 def ambient_mesh():
-    """jax's own currently-active mesh, or ``None``.
-
-    Checks the abstract mesh (``jax.set_mesh`` / ``use_mesh`` era) first,
-    then the legacy resource-env physical mesh (``with mesh:`` era).  This
-    is the *fallback* discovery path — explicit ``MeshContext`` threading
-    (repro.compat.meshctx) is the primary one.
-    """
-    if HAS_GET_ABSTRACT_MESH:
-        try:
-            m = jax.sharding.get_abstract_mesh()
-            if m is not None and not getattr(m, "empty", False):
-                return m
-        except Exception:
-            pass
-    env = _resource_env()
-    if env is not None:
-        pm = getattr(env, "physical_mesh", None)
-        if pm is not None and not getattr(pm, "empty", True):
-            return pm
+    """jax's own currently active mesh (set by ``jax.set_mesh``), or
+    ``None``.  This is the *fallback* discovery path — explicit
+    ``MeshContext`` threading (repro.compat.meshctx) is the primary one."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is not None and not m.empty:
+        return m
     return None
 
 
 def native_mesh_scope(mesh):
-    """Context manager activating ``mesh`` the way this jax supports.
-
-    Preference order: ``jax.sharding.use_mesh`` > ``jax.set_mesh`` (both
-    scope the abstract mesh on newer jax) > the legacy ``Mesh`` context
-    manager (sets the 0.4.x resource env, which is what makes bare
-    ``PartitionSpec`` constraints legal there).  Abstract meshes on old jax
-    (not activatable) and ``None`` get a null scope.
-    """
+    """Context manager activating ``mesh`` with ``jax.set_mesh`` (``None``
+    gets a null scope)."""
     if mesh is None:
         return contextlib.nullcontext()
-    if HAS_USE_MESH:
-        return jax.sharding.use_mesh(mesh)
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    if isinstance(mesh, Mesh):
-        return mesh  # legacy: Mesh is its own context manager
-    return contextlib.nullcontext()
-
-
-# ---------------------------------------------------------------------------
-# Sharding constraints
-# ---------------------------------------------------------------------------
+    return jax.set_mesh(mesh)
 
 
 def with_sharding_constraint(x, spec, mesh=None):
-    """``jax.lax.with_sharding_constraint`` that cannot version-crash.
+    """``jax.lax.with_sharding_constraint`` that cannot crash for want of
+    a mesh.
 
     * ``NamedSharding`` specs pass straight through.
     * With a concrete :class:`Mesh` (given or ambient) the spec is bound
-      into a ``NamedSharding`` — legal on every jax, active context or not.
-    * With only an abstract mesh (new jax), the bare spec is used.
+      into a ``NamedSharding``.
+    * With only an abstract mesh, the bare spec is used.
     * With no mesh at all the constraint is an identity, so single-device
       smoke paths never pay for distribution plumbing.
     """
@@ -184,67 +83,25 @@ def with_sharding_constraint(x, spec, mesh=None):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
-# ---------------------------------------------------------------------------
-# Compiled-executable analyses
-# ---------------------------------------------------------------------------
-
-
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to a flat dict.
-
-    Old jax returns a one-element list of per-program dicts; new jax
-    returns the dict directly; either may be empty/None on some backends.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
-
-
-# ---------------------------------------------------------------------------
-# Moved transforms
-# ---------------------------------------------------------------------------
-
-
-def _resolve_shard_map():
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        return impl
-    try:
-        from jax.experimental.shard_map import shard_map as impl
-
-        return impl
-    except ImportError:
-        return None
+    """``Compiled.cost_analysis()`` as a dict (empty where the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """``shard_map`` wherever this jax keeps it (top-level or experimental)."""
-    impl = _resolve_shard_map()
-    if impl is None:
-        raise NotImplementedError(
-            f"shard_map is not available in jax {jax.__version__}"
-        )
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    """``jax.shard_map`` with the mesh passed explicitly."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 def pjit(fun, **kwargs):
-    """Partitioned jit entry point.
-
-    ``jax.jit`` accepts in/out_shardings on every version this repo
-    supports (pjit merged into jit in 0.4); kept as a named entry point so
-    call sites survive a future split the same way they survived the merge.
-    """
+    """Partitioned jit entry point: ``jax.jit`` takes in/out_shardings;
+    kept as a named entry point so call sites survive a future split."""
     return jax.jit(fun, **kwargs)
 
 
 __all__ = [
-    "JAX_VERSION",
-    "HAS_AXIS_TYPE",
-    "HAS_GET_ABSTRACT_MESH",
-    "HAS_SET_MESH",
-    "HAS_USE_MESH",
-    "HAS_MAKE_MESH",
     "make_mesh",
     "ambient_mesh",
     "native_mesh_scope",

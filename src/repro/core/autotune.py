@@ -161,6 +161,13 @@ def pick_w_block(
     return max(1, min(w, pow2_ceil(max_width)))
 
 
+def lane_waste(c: int) -> float:
+    """VMEM bytes per useful byte of the RHS gather table for slice height
+    ``c``: the (k, R, lanes) table holds ``min(c, 128)`` entries per row
+    and every row is padded to the 128 lanes of a vreg."""
+    return LANE / min(max(int(c), 1), LANE)
+
+
 def pick_k_block(
     c: int,
     n_cols: int,
@@ -179,16 +186,17 @@ def pick_k_block(
     BlockSpec operand through a *pair* of VMEM buffers, so the honest
     per-column price of X is 16 B (2 x f64), not 8 — same for the output
     tile; this is the model :func:`repro.analysis.preflight.plan_spmm_sell`
-    enforces.  Pass the co-selected ``w_block`` so the slab tile term
-    prices the tile that will actually run, keeping the
-    (w_block, k_block) pair JOINTLY inside the budget rather than each
-    fitting alone.
+    enforces, lanes padded to 128 (:func:`lane_waste`).  Pass the
+    co-selected ``w_block`` so the slab tile term prices the tile that will
+    actually run, keeping the (w_block, k_block) pair JOINTLY inside the
+    budget rather than each fitting alone.
     """
     slab_tile = 2 * w_block * c * 12.0        # double-buffered cols+vals
+    x_col = 16.0 * lane_waste(c)
     k = 1
     while (
         k * 2 <= k_max
-        and 16.0 * (n_cols + c) * (k * 2) + slab_tile <= vmem_budget
+        and x_col * (n_cols + c) * (k * 2) + slab_tile <= vmem_budget
     ):
         k *= 2
     return k
@@ -211,11 +219,17 @@ def pick_stream_tiles(
     The column tile dominates X traffic amortization (each tile is reused
     across ``row_tile`` slices), so it is grown first to half the budget;
     the row tile then fills what remains.  Both stay powers of two so the
-    host-side padding in the wrapper is a single static pad.
+    host-side padding in the wrapper is a single static pad.  The k tile
+    is priced at its widest (:func:`repro.sparse.formats.widest_k_tile`):
+    a group of 8 requests runs as one 8-column tile whatever ``k_block``.
     """
+    from repro.sparse.formats import widest_k_tile
+
+    kt = widest_k_tile(k_block)
     slab_tile = 2 * w_block * c * 12.0
-    x_col = 16.0 * max(k_block, 1)            # double-buffered X bytes/column
-    acc_row = 8.0 * c * max(k_block, 1)       # accumulator bytes per slice
+    # double-buffered X bytes per column, table lanes padded to 128
+    x_col = 16.0 * kt * lane_waste(c)
+    acc_row = 8.0 * c * kt                    # accumulator bytes per slice
     ct = LANE
     while (
         ct * 2 <= col_tile_max
@@ -312,9 +326,12 @@ def tune_sell_layout(
     # on the streaming schedule, where X residency is a (col_tile, k_tile)
     # slice the tuner controls — so when *no* candidate fits resident, the
     # operand is stream-only and (C, sigma) is scored without the filter.
-    x_resident = 16.0 * n_cols
+    def x_resident(c: int) -> float:
+        return 16.0 * n_cols * lane_waste(c)
+
     rows = score(
-        c for c in cands if x_resident + 2 * SUBLANE * c * 12.0 <= vmem_budget
+        c for c in cands
+        if x_resident(c) + 2 * SUBLANE * c * 12.0 <= vmem_budget
     )
     stream_only = not rows
     if stream_only:
@@ -333,7 +350,8 @@ def tune_sell_layout(
         best[0], max(max_w, 1),
         vmem_budget=(
             vmem_budget / 8 if stream_only
-            else max(vmem_budget - x_resident, 2 * SUBLANE * best[0] * 12.0)
+            else max(vmem_budget - x_resident(best[0]),
+                     2 * SUBLANE * best[0] * 12.0)
         ),
     )
     k_block = pick_k_block(

@@ -5,6 +5,7 @@ from repro.graphs.gen import (
     SellGraphSlabs,
     bfs_reference,
     graph_to_sell_slabs,
+    in_degree,
     pagerank_reference,
     random_graph,
     rmat_graph,
@@ -15,6 +16,7 @@ __all__ = [
     "SellGraphSlabs",
     "bfs_reference",
     "graph_to_sell_slabs",
+    "in_degree",
     "pagerank_reference",
     "random_graph",
     "rmat_graph",

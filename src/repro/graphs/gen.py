@@ -92,55 +92,81 @@ class SellGraphSlabs:
         return self.padded_entries / max(self.n_edges, 1)
 
 
-def graph_to_sell_slabs(
-    g: EllpackGraph, c: int, sigma: int | None = None
-) -> SellGraphSlabs:
-    """Bucket a degree-padded graph into SELL slabs (vectorized).
+def _edges(g: EllpackGraph, reverse: bool = False
+           ) -> tuple[np.ndarray, np.ndarray]:
+    """(node, neighbour) of every stored edge, grouped by node in ascending
+    order: each node's out-neighbours in adjacency order, or with
+    ``reverse`` its in-neighbours by ascending source id (the rows of
+    ``g.transpose()``)."""
+    src, k = np.nonzero(g.adj != PAD)
+    dst = g.adj[src, k].astype(np.int64)
+    if not reverse:
+        return src, dst
+    by_dst = np.argsort(dst, kind="stable")
+    return dst[by_dst], src[by_dst]
 
-    The adjacency rows are already materialized in ``g.adj``; slabs are just
-    a degree-sorted row gather plus per-bucket column trims, so conversion
-    is a handful of array ops even at millions of nodes.
+
+def _pack_edges(nodes: np.ndarray, nbrs: np.ndarray, n: int, c: int,
+                sigma: int) -> SellGraphSlabs:
+    """SELL slabs of an adjacency given as (node, neighbour) pairs grouped
+    by ascending node: each node's neighbours fill its lane left to right.
+
+    Only the padded slots are allocated — never the n x (max degree)
+    degree-padded matrix, which for a skewed graph whose hub has tens of
+    thousands of neighbours is tens of GB.
     """
     from repro.sparse.formats import next_pow2, sigma_sort_order, slice_widths
 
-    sigma = int(sigma or 8 * c)
-    n = g.n_nodes
-    deg = (g.adj != PAD).sum(axis=1).astype(np.int64)
+    deg = np.bincount(nodes, minlength=n).astype(np.int64)
+    starts = np.zeros(n + 1, np.int64)
+    np.cumsum(deg, out=starts[1:])
+    within = np.arange(len(nodes), dtype=np.int64) - starts[nodes]
     order = sigma_sort_order(deg, sigma)
     bwidths = next_pow2(slice_widths(deg, order, c))
     n_slices = len(bwidths)
-
     nodes_padded = np.full(n_slices * c, n, np.int64)
     nodes_padded[:n] = order
     nodes_by_slice = nodes_padded.reshape(n_slices, c).astype(np.int32)
-
-    # Sorted adjacency with a PAD guard row for padding lanes.
-    adj_guard = np.concatenate(
-        [g.adj, np.full((1, g.width), PAD, np.int32)], axis=0
-    )
+    pos = np.empty(n, np.int64)
+    pos[order] = np.arange(n)
+    e_slice, e_lane = pos[nodes] // c, pos[nodes] % c
     bucket_adj, bucket_nodes = [], []
     for w in np.unique(bwidths):
         ids = np.nonzero(bwidths == w)[0]
-        rows = adj_guard[nodes_by_slice[ids].reshape(-1)]   # (S_b*C, width)
-        w = int(w)
-        if w <= g.width:
-            rows = rows[:, :w]
-        else:
-            rows = np.pad(rows, ((0, 0), (0, w - g.width)), constant_values=PAD)
-        bucket_adj.append(np.ascontiguousarray(rows.reshape(len(ids), c, w)))
+        local = np.full(n_slices, -1, np.int64)
+        local[ids] = np.arange(len(ids))
+        sel = local[e_slice] >= 0
+        adj = np.full((len(ids), c, int(w)), PAD, np.int32)
+        adj[local[e_slice[sel]], e_lane[sel], within[sel]] = nbrs[sel]
+        bucket_adj.append(adj)
         bucket_nodes.append(nodes_by_slice[ids])
-    kept = sum(int((a != PAD).sum()) for a in bucket_adj)
-    if kept != int(deg.sum()):
-        raise ValueError(
-            "adjacency rows must be left-justified (neighbors in columns "
-            "[0, degree)); the width trim dropped edges"
-        )
     return SellGraphSlabs(
         bucket_adj=tuple(bucket_adj),
         bucket_nodes=tuple(bucket_nodes),
         n_nodes=n,
         sigma=sigma,
     )
+
+
+def graph_to_sell_slabs(
+    g: EllpackGraph, c: int, sigma: int | None = None, *,
+    reverse: bool = False,
+) -> SellGraphSlabs:
+    """Bucket a graph's adjacency into SELL slabs (vectorized).
+
+    ``reverse=True`` packs the in-neighbours — the slabs of
+    ``graph_to_sell_slabs(g.transpose(), ...)``, which the pull-style BFS
+    and PageRank kernels read — straight from the edge list, without the
+    degree-padded reverse graph.
+    """
+    return _pack_edges(*_edges(g, reverse), g.n_nodes, c,
+                       int(sigma or 8 * c))
+
+
+def in_degree(g: EllpackGraph) -> np.ndarray:
+    """In-degree of every node (the row lengths of the reverse graph)."""
+    return np.bincount(g.adj[g.adj != PAD], minlength=g.n_nodes).astype(
+        np.int64)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,13 +209,20 @@ class ShardedGraphSlabs:
     def slices_per_shard(self) -> tuple[int, ...]:
         return tuple(a.shape[1] for a in self.bucket_adj)
 
+    @property
+    def pad_factor(self) -> float:
+        edges = sum(int((a != PAD).sum()) for a in self.bucket_adj)
+        return sum(a.size for a in self.bucket_adj) / max(edges, 1)
+
 
 def shard_graph_slabs(
-    g: EllpackGraph, c: int, n_shards: int, sigma: int | None = None
+    g: EllpackGraph, c: int, n_shards: int, sigma: int | None = None, *,
+    reverse: bool = False,
 ) -> ShardedGraphSlabs:
-    """Node-partition a (reverse) graph into per-device SELL slabs.
+    """Node-partition a graph's adjacency (in-neighbours with ``reverse``,
+    as for :func:`graph_to_sell_slabs`) into per-device SELL slabs.
 
-    Nodes split into contiguous in-degree-balanced ranges; each range is
+    Nodes split into contiguous degree-balanced ranges; each range is
     degree-sorted and bucketed *locally* (so no slice mixes nodes across
     the partition), then the per-shard structures are padded to the union
     bucket layout exactly as :func:`repro.sparse.formats.shard_slabs` does
@@ -199,13 +232,14 @@ def shard_graph_slabs(
 
     sigma = int(sigma or 8 * c)
     n = g.n_nodes
-    deg = (g.adj != PAD).sum(axis=1).astype(np.int64)
-    ranges = shard_row_ranges(deg, n_shards)
+    nodes, nbrs = _edges(g, reverse)
+    ranges = shard_row_ranges(np.bincount(nodes, minlength=n), n_shards)
     n_shards = len(ranges)
     shards = []
     for lo, hi in ranges:
-        sub = EllpackGraph(adj=g.adj[lo:hi], n_nodes=hi - lo)
-        shards.append((lo, graph_to_sell_slabs(sub, c=c, sigma=sigma)))
+        a, b = np.searchsorted(nodes, [lo, hi])
+        shards.append((lo, _pack_edges(nodes[a:b] - lo, nbrs[a:b], hi - lo,
+                                       c, sigma)))
 
     per_shard = [dict(zip(s.widths, range(len(s.bucket_adj))))
                  for _, s in shards]
@@ -286,15 +320,19 @@ def rmat_graph(
                          r2 >= a / max(a + b, 1e-9))
         src |= s_bit.astype(np.int64) << bit
         dst |= d_bit.astype(np.int64) << bit
+    # keep each source's first ``cap`` non-loop edges in generation order
     cap = degree_cap_factor * avg_degree
-    adj_lists: list[list[int]] = [[] for _ in range(n_nodes)]
-    for s, d in zip(src, dst):
-        if len(adj_lists[s]) < cap and s != d:
-            adj_lists[s].append(int(d))
-    width = max(1, max(len(l) for l in adj_lists))
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    order = np.argsort(src, kind="stable")
+    src, dst = src[order], dst[order]
+    starts = np.searchsorted(src, np.arange(n_nodes))
+    slot = np.arange(len(src)) - starts[src]
+    keep = slot < cap
+    src, dst, slot = src[keep], dst[keep], slot[keep]
+    width = max(1, int(slot.max()) + 1 if len(slot) else 1)
     adj = np.full((n_nodes, width), PAD, np.int32)
-    for v, l in enumerate(adj_lists):
-        adj[v, : len(l)] = l
+    adj[src, slot] = dst
     return EllpackGraph(adj=adj, n_nodes=n_nodes)
 
 
@@ -326,14 +364,17 @@ def pagerank_reference(
     iters: int = 20,
     dtype=np.float64,
 ) -> np.ndarray:
-    """Pull-style power iteration with dangling-mass redistribution."""
+    """Pull-style power iteration with dangling-mass redistribution (each
+    node pulls rank / out-degree from its in-neighbours, summed over the
+    edge list)."""
     n = g.n_nodes
     out_deg = g.out_degree.astype(dtype)
-    rt = g.transpose()
+    src, dst = _edges(g)
     rank = np.full(n, 1.0 / n, dtype)
     for _ in range(iters):
         contrib = np.where(out_deg > 0, rank / np.maximum(out_deg, 1), 0.0)
         dangling = rank[out_deg == 0].sum()
-        gathered = np.where(rt.adj == PAD, 0.0, contrib[np.clip(rt.adj, 0, n - 1)])
-        rank = (1.0 - damping) / n + damping * (gathered.sum(axis=1) + dangling / n)
+        pulled = np.bincount(dst, weights=contrib[src], minlength=n)
+        rank = ((1.0 - damping) / n
+                + damping * (pulled + dangling / n)).astype(dtype)
     return rank

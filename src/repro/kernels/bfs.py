@@ -10,10 +10,10 @@ TPU-friendly semantics).
 
 The SELL variants are thin drivers over the batched execution core
 (:mod:`repro.kernels.sell_core`): the frontier state is a stacked
-(n + 1, k) column matrix — one column per BFS source — and only the
-combine op (``any neighbor on the previous level``) lives here.  The
-per-bucket launch + scatter loop is :func:`sell_core.bucketed_node_step`,
-shared with PageRank.
+(n + 1, k) column matrix — one column per BFS source — read through the
+core's in-VMEM lane gather, and only the combine op (``any neighbor on the
+previous level``) lives here.  The per-bucket launch + scatter loop is
+:func:`sell_core.bucketed_node_step`, shared with PageRank.
 
 Grid: (n_nodes / vl,).  The dist array stays VMEM-resident (2^15 nodes =
 128 KiB of i32), adjacency streams through.  Node counts that do not divide
@@ -29,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import sell_core
+from repro.kernels.backend import resolve_interpret
+from repro.sparse.formats import SUBLANES
 
 PAD = -1
 INF = np.iinfo(np.int32).max
@@ -53,7 +55,7 @@ def bfs_step(
     level: jnp.ndarray,
     *,
     vl: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One bottom-up BFS level over ELLPACK adjacency (n, width).
 
@@ -79,7 +81,7 @@ def bfs_step(
         ],
         out_specs=pl.BlockSpec((vl,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), dist.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(adj, dist, level)
     return out[:n]
 
@@ -87,21 +89,31 @@ def bfs_step(
 def _bfs_sell_step_kernel(adj_ref, nodes_ref, dist_ref, level_ref, out_ref):
     """The BFS combine op: any in-neighbor on the previous level.
 
-    Rank-polymorphic over the frontier state: (n + 1,) distances keep the
-    single-source fast path, (n + 1, k) advances k stacked sources (one
-    RHS column each) through the same launch.
+    One output row per stacked source column kk of the (k, R, lanes)
+    distance table: a node still at INF joins the frontier at ``level``
+    when any of its in-neighbors sits at ``level - 1``.
     """
     level = level_ref[0]
-    adj = adj_ref[0]                          # (C, W_b)
-    nodes = nodes_ref[0]                      # (C,) original ids, n for pads
-    mask = adj != PAD
-    safe = jnp.where(mask, adj, 0)
-    nd = dist_ref[safe]                       # (C, W_b) or (C, W_b, k)
-    if nd.ndim == 3:
-        mask = mask[..., None]
-    hit = jnp.any(jnp.where(mask, nd == level - 1, False), axis=1)
-    mine = dist_ref[nodes]                    # gather through the sigma-sort
-    out_ref[0] = jnp.where((mine == INF) & hit, level, mine)
+    width, c = adj_ref.shape[1:]
+    rows = min(width, SUBLANES)
+
+    def column(kk, carry):
+        def block(b, hit):
+            adj = adj_ref[0, pl.ds(b * rows, rows), :]
+            mask = adj != PAD
+            nd = sell_core.gather(dist_ref, kk, jnp.where(mask, adj, 0))
+            on_frontier = (mask & (nd == level - 1)).astype(jnp.int32)
+            return jnp.maximum(hit, jnp.max(on_frontier, axis=0,
+                                            keepdims=True))
+
+        hit = jax.lax.fori_loop(0, width // rows, block,
+                                jnp.zeros((1, c), jnp.int32))
+        mine = sell_core.gather(dist_ref, kk, nodes_ref[0])  # via sigma-sort
+        out_ref[0, pl.ds(kk, 1), :] = jnp.where(
+            (mine == INF) & (hit > 0), level, mine)
+        return carry
+
+    jax.lax.fori_loop(0, dist_ref.shape[0], column, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -111,7 +123,7 @@ def bfs_step_sell(
     dist: jnp.ndarray,
     level: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One bottom-up level over width-bucketed, degree-sorted adjacency.
 
@@ -119,11 +131,13 @@ def bfs_step_sell(
     sources (the dump slot stays INF); returns the updated copy with the
     same shape.  One launch set advances every column.
     """
+    cols = dist if dist.ndim == 2 else dist[:, None]
     out = sell_core.bucketed_node_step(
         _bfs_sell_step_kernel, bucket_adj, bucket_nodes,
-        (dist, level), dist, interpret=interpret,
+        cols, level, cols, interpret=interpret,
     )
-    return out.at[-1].set(INF)                # keep the dump slot inert
+    out = out.at[-1].set(INF)                 # keep the dump slot inert
+    return out if dist.ndim == 2 else out[:, 0]
 
 
 def bfs_sell(
@@ -133,7 +147,7 @@ def bfs_sell(
     source,
     *,
     max_levels: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Full BFS over bucketed SELL adjacency, batched over sources.
 
@@ -143,14 +157,10 @@ def bfs_sell(
     — one column per source — for a sequence.  Columns that converge early
     stay fixed while the rest keep expanding.
     """
-    scalar = np.ndim(source) == 0
     sources = np.atleast_1d(np.asarray(source, np.int64))
     k = len(sources)
-    if scalar:                                # single-column fast path
-        dist = jnp.full((n_nodes + 1,), INF, jnp.int32).at[int(source)].set(0)
-    else:
-        dist = jnp.full((n_nodes + 1, k), INF, jnp.int32)
-        dist = dist.at[jnp.asarray(sources), jnp.arange(k)].set(0)
+    dist = jnp.full((n_nodes + 1, k), INF, jnp.int32)
+    dist = dist.at[jnp.asarray(sources), jnp.arange(k)].set(0)
     max_levels = max_levels or n_nodes
     for level in range(1, max_levels + 1):
         new = bfs_step_sell(
@@ -160,7 +170,7 @@ def bfs_sell(
         if bool(jnp.all(new == dist)):
             break
         dist = new
-    return dist[:n_nodes]
+    return dist[:n_nodes, 0] if np.ndim(source) == 0 else dist[:n_nodes]
 
 
 def bfs(
@@ -169,7 +179,7 @@ def bfs(
     *,
     vl: int = 256,
     max_levels: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Full BFS: fixed-point iteration of :func:`bfs_step`.
 

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _gather_kernel(ids_ref, table_ref, out_ref):
     ids = ids_ref[...]                       # (vl,) int32
@@ -32,7 +34,7 @@ def embedding_gather(
     ids: jnp.ndarray,
     *,
     vl: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """out[i] = table[ids[i]].  ids: (T,) int32; table: (V, d)."""
     t = ids.shape[0]
@@ -50,7 +52,7 @@ def embedding_gather(
         ],
         out_specs=pl.BlockSpec((vl, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ids.shape[0], d), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids, table)
     return out[:t]
 

@@ -4,8 +4,9 @@ These are the APIs the examples/benchmarks call: they take the host-side
 substrate objects (:class:`repro.sparse.EllpackMatrix`,
 :class:`repro.sparse.SellSlabs`, :class:`repro.graphs.EllpackGraph`), move
 them to device, pad to the chosen VL, dispatch the kernel matching the
-format, and trim the result.  ``interpret`` defaults to "not on TPU" so the
-same call sites run interpreted on CPU and compiled on real hardware.
+format, and trim the result.  ``interpret`` defaults to "not on TPU"
+(:func:`repro.kernels.backend.default_interpret`) so the same call sites
+run interpreted on CPU and compiled on real hardware.
 """
 from __future__ import annotations
 
@@ -35,6 +36,7 @@ from repro.kernels import fft as fft_k
 from repro.kernels import pagerank as pr_k
 from repro.kernels import sell_core, sell_shard
 from repro.kernels import spmv as spmv_k
+from repro.kernels.backend import float_dtype, resolve_interpret
 from repro.kernels.execspec import _UNSET, ExecSpec
 from repro.kernels.ref import fft_twiddles
 from repro.obs import Stopwatch
@@ -52,10 +54,6 @@ from repro.sparse.formats import (
 
 PAD = -1
 INF = np.iinfo(np.int32).max
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -133,32 +131,40 @@ def _shard_cached(slabs: SellSlabs, n_shards: int, cache):
     return sharded
 
 
-def _shard_graph_cached(rgraph: EllpackGraph, vl: int, sigma: int | None,
+def _shard_graph_cached(graph: EllpackGraph, vl: int, sigma: int | None,
                         n_shards: int, cache):
-    """Node-partitioned graph slabs for a device mesh, memoized (see
-    :func:`_shard_cached`)."""
+    """Node-partitioned reverse-adjacency slabs for a device mesh, memoized
+    (see :func:`_shard_cached`)."""
     from repro.service.tunecache import operand_signature
 
     cache = cache if cache is not None else default_tune_cache()
-    sig = operand_signature(rgraph)
-    key = ("shard-graph", sig.key, int(vl), int(sigma or 0), int(n_shards))
+    sig = operand_signature(graph)
+    key = ("shard-graph-rev", sig.key, int(vl), int(sigma or 0),
+           int(n_shards))
     sg = cache.packed_get(key)
     if sg is None:
-        sg = shard_graph_slabs(rgraph, c=vl, n_shards=n_shards, sigma=sigma)
+        sg = shard_graph_slabs(graph, c=vl, n_shards=n_shards, sigma=sigma,
+                               reverse=True)
         cache.packed_put(key, sg)
     return sg
 
 
-def _sharded_graph_meta(sg) -> SlabMeta:
+def _sharded_graph_meta(sg, check_bounds: bool = False) -> SlabMeta:
     """Per-device :class:`SlabMeta` of sharded graph slabs: every device
     executes ``slices_per_shard`` slices of each union bucket against the
     full replicated state, which is exactly what the single-device
-    ``plan_bfs_sell``/``plan_pagerank_sell`` price."""
+    ``plan_bfs_sell``/``plan_pagerank_sell`` price.  ``check_bounds``
+    scans the stored ids, as :meth:`SlabMeta.from_slabs` does."""
+    scan = check_bounds and any(a.size for a in sg.bucket_adj)
     return SlabMeta(
         kind="graph", c=sg.c, widths=sg.widths,
         n_slices=sg.slices_per_shard, n_rows=sg.n_nodes, n_cols=sg.n_nodes,
         val_dtype=None, idx_dtype=str(sg.bucket_adj[0].dtype)
         if sg.bucket_adj else "int32",
+        idx_min=min(int(a.min()) for a in sg.bucket_adj if a.size)
+        if scan else None,
+        idx_max=max(int(a.max()) for a in sg.bucket_adj if a.size)
+        if scan else None,
     )
 
 
@@ -352,7 +358,7 @@ def spmm(
             f"unknown mode {spec.mode!r}: expected one of {_SPMM_MODES}")
     kb = spec.k_block if spec.k_block is not None \
         else min(8, sell_core.pow2_ceil(x.shape[1]))
-    interp = default_interpret() if spec.interpret is None else spec.interpret
+    interp = resolve_interpret(spec.interpret)
     matrix = _normalize_matrix(matrix, spec)
     if isinstance(matrix, SellSlabs):
         if spec.n_devices() > 1:
@@ -431,7 +437,7 @@ def spmv(
     if spec.mode not in _SPMM_MODES:
         raise ValueError(
             f"unknown mode {spec.mode!r}: expected one of {_SPMM_MODES}")
-    interp = default_interpret() if spec.interpret is None else spec.interpret
+    interp = resolve_interpret(spec.interpret)
     matrix = _normalize_matrix(matrix, spec)
     if isinstance(matrix, SellSlabs):
         if spec.n_devices() > 1:
@@ -591,7 +597,7 @@ def fft(
     n = re.shape[-1]
     if n & (n - 1):
         raise ValueError(f"n must be a power of two, got {n}")
-    interp = default_interpret() if spec.interpret is None else spec.interpret
+    interp = resolve_interpret(spec.interpret)
     wre, wim = fft_twiddles(n, re.dtype)
     bb = min(spec.b_block, re.shape[0])
     plan_fft_stockham(
@@ -639,18 +645,18 @@ def bfs(
     if spec.layout not in ("ell", "sell"):
         raise ValueError(
             f"unknown layout {spec.layout!r}: expected 'ell' or 'sell'")
-    interp = default_interpret() if spec.interpret is None else spec.interpret
+    interp = resolve_interpret(spec.interpret)
     n = graph.n_nodes
     # Bottom-up expansion needs *in*-neighbors: a node joins the frontier if
     # one of the nodes that point AT it was reached last level.
-    rgraph = graph.transpose()
     if spec.n_devices() > 1:
         if spec.layout != "sell":
             raise ValueError(
                 "multi-device placement requires layout='sell' (the "
                 "ELLPACK drive has no sharded path)")
         sg = _shard_graph_cached(
-            rgraph, spec.vl, spec.sigma, spec.n_devices(), spec.cache)
+            graph, spec.vl, spec.sigma, spec.n_devices(),
+            spec.cache)
         plan_bfs_sell(
             _sharded_graph_meta(sg), k=int(np.size(source)),
         ).raise_if_invalid()
@@ -658,7 +664,8 @@ def bfs(
             sg, source, mesh=spec.resolved_placement(), interpret=interp)
         return np.asarray(dist)
     if spec.layout == "sell":
-        slabs = graph_to_sell_slabs(rgraph, c=spec.vl, sigma=spec.sigma)
+        slabs = graph_to_sell_slabs(graph, c=spec.vl, sigma=spec.sigma,
+                                    reverse=True)
         plan_bfs_sell(
             SlabMeta.from_slabs(slabs), k=int(np.size(source)),
         ).raise_if_invalid()
@@ -668,7 +675,7 @@ def bfs(
             n, source, interpret=interp,
         )
         return np.asarray(dist)
-    radj = jnp.asarray(rgraph.adj)            # bfs_step auto-pads to vl
+    radj = jnp.asarray(graph.transpose().adj)  # bfs_step auto-pads to vl
     if np.ndim(source) == 0:
         return np.asarray(
             bfs_k.bfs(radj, source, vl=spec.vl, interpret=interp))
@@ -715,7 +722,7 @@ def pagerank(
     if spec.layout not in ("ell", "sell"):
         raise ValueError(
             f"unknown layout {spec.layout!r}: expected 'ell' or 'sell'")
-    interp = default_interpret() if spec.interpret is None else spec.interpret
+    interp = resolve_interpret(spec.interpret)
     n = graph.n_nodes
     if spec.n_devices() > 1:
         if spec.layout != "sell":
@@ -723,21 +730,21 @@ def pagerank(
                 "multi-device placement requires layout='sell' (the "
                 "ELLPACK drive has no sharded path)")
         sg = _shard_graph_cached(
-            graph.transpose(), spec.vl, spec.sigma, spec.n_devices(),
+            graph, spec.vl, spec.sigma, spec.n_devices(),
             spec.cache)
         plan_pagerank_sell(
             _sharded_graph_meta(sg),
             k=max(int(np.size(damping)), int(np.size(iters))),
         ).raise_if_invalid()
         rank = sell_shard.pagerank_sell_sharded(
-            sg, jnp.asarray(graph.out_degree.astype(np.float64)),
+            sg, jnp.asarray(graph.out_degree, float_dtype()),
             mesh=spec.resolved_placement(), damping=damping, iters=iters,
             interpret=interp,
         )
         return np.asarray(rank)
     if spec.layout == "sell":
-        slabs = graph_to_sell_slabs(
-            graph.transpose(), c=spec.vl, sigma=spec.sigma)
+        slabs = graph_to_sell_slabs(graph, c=spec.vl, sigma=spec.sigma,
+                                    reverse=True)
         plan_pagerank_sell(
             SlabMeta.from_slabs(slabs),
             k=max(int(np.size(damping)), int(np.size(iters))),
@@ -745,12 +752,12 @@ def pagerank(
         rank = pr_k.pagerank_sell(
             tuple(jnp.asarray(a) for a in slabs.bucket_adj),
             tuple(jnp.asarray(m) for m in slabs.bucket_nodes),
-            jnp.asarray(graph.out_degree.astype(np.float64)),
+            jnp.asarray(graph.out_degree, float_dtype()),
             n, damping=damping, iters=iters, interpret=interp,
         )
         return np.asarray(rank)
     radj = jnp.asarray(graph.transpose().adj)  # pagerank_step auto-pads
-    deg = jnp.asarray(graph.out_degree.astype(np.float64))
+    deg = jnp.asarray(graph.out_degree, float_dtype())
     if np.ndim(damping) == 0 and np.ndim(iters) == 0:
         rank = pr_k.pagerank(
             radj, deg, damping=damping, iters=iters, vl=spec.vl,
@@ -830,7 +837,7 @@ def moe_dispatch(
             f"{type(routing).__name__}")
     kb = spec.k_block if spec.k_block is not None \
         else min(8, sell_core.pow2_ceil(x.shape[1]))
-    interp = default_interpret() if spec.interpret is None else spec.interpret
+    interp = resolve_interpret(spec.interpret)
     meta = SlabMeta.from_slabs(slabs)
     plan_moe_dispatch(
         meta, k=int(x.shape[1]), x_dtype=str(x.dtype), top_k=top_k,

@@ -7,8 +7,8 @@ gather per adjacency column tile and reduces them.  The contribution vector
 
 The SELL variants are thin drivers over the batched execution core
 (:mod:`repro.kernels.sell_core`): the power iterate is a stacked (n + 1, k)
-column matrix — one column per (damping, iters) configuration — and only
-the combine op (damped pull-sum plus dangling mass) lives here.  The
+column matrix — one column per (damping, iters) configuration — read
+through the core's in-VMEM lane gather, and only the combine op (damped pull-sum plus dangling mass) lives here.  The
 per-bucket launch + scatter loop is :func:`sell_core.bucketed_node_step`,
 shared with BFS.
 
@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import sell_core
+from repro.kernels.backend import float_dtype, resolve_interpret
+from repro.sparse.formats import SUBLANES
 
 PAD = -1
 
@@ -47,7 +49,7 @@ def pagerank_step(
     consts: jnp.ndarray,
     *,
     vl: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One power-iteration step.
 
@@ -73,29 +75,39 @@ def pagerank_step(
         ],
         out_specs=pl.BlockSpec((vl,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), contrib.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(radj, contrib, consts)
     return out[:n]
 
 
-def _pr_sell_step_kernel(radj_ref, nodes_ref, contrib_ref, consts_ref, out_ref):
+def _pr_sell_step_kernel(radj_ref, nodes_ref, contrib_ref, consts_ref,
+                         out_ref):
     """The PageRank combine op: damped pull-sum.
 
-    Rank-polymorphic over the iterate: (n + 1,) contributions keep the
-    single-configuration fast path, (n + 1, k) advances k stacked
-    (damping, iters) configurations (one RHS column each, consts (3, k))
-    through the same launch.
+    One output row per stacked (damping, iters) column kk of the
+    (k, R, lanes) contribution table; ``consts_ref`` is the (3, k)
+    [(1-d)/n, d, dangling/n] table in SMEM.
     """
     del nodes_ref                             # pull-only: no own-state gather
-    radj = radj_ref[0]                        # (C, W_b)
-    mask = radj != PAD
-    safe = jnp.where(mask, radj, 0)
-    gathered = contrib_ref[safe]              # (C, W_b) or (C, W_b, k)
-    if gathered.ndim == 3:
-        mask = mask[..., None]
-    pulled = jnp.sum(jnp.where(mask, gathered, 0.0), axis=1)
-    base, damping, dangling_term = consts_ref[0], consts_ref[1], consts_ref[2]
-    out_ref[0] = base + damping * (pulled + dangling_term)
+    width, c = radj_ref.shape[1:]
+    rows = min(width, SUBLANES)
+
+    def column(kk, carry):
+        def block(b, acc):
+            radj = radj_ref[0, pl.ds(b * rows, rows), :]
+            mask = radj != PAD
+            got = sell_core.gather(contrib_ref, kk, jnp.where(mask, radj, 0))
+            return acc + jnp.sum(jnp.where(mask, got, 0.0), axis=0,
+                                 keepdims=True)
+
+        pulled = jax.lax.fori_loop(0, width // rows, block,
+                                   jnp.zeros((1, c), out_ref.dtype))
+        base, damping = consts_ref[0, kk], consts_ref[1, kk]
+        out_ref[0, pl.ds(kk, 1), :] = base + damping * (
+            pulled + consts_ref[2, kk])
+        return carry
+
+    jax.lax.fori_loop(0, contrib_ref.shape[0], column, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -105,7 +117,7 @@ def pagerank_step_sell(
     contrib: jnp.ndarray,
     consts: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One power step over width-bucketed, in-degree-sorted adjacency.
 
@@ -115,11 +127,14 @@ def pagerank_step_sell(
     through ``bucket_nodes``; returns the new rank matrix, same shape as
     ``contrib``.
     """
+    cols = contrib if contrib.ndim == 2 else contrib[:, None]
     out = sell_core.bucketed_node_step(
         _pr_sell_step_kernel, bucket_radj, bucket_nodes,
-        (contrib, consts), jnp.zeros_like(contrib), interpret=interpret,
+        cols, consts.reshape(3, cols.shape[1]), jnp.zeros_like(cols),
+        interpret=interpret,
     )
-    return out.at[-1].set(0.0)                # keep the dump slot inert
+    out = out.at[-1].set(0.0)                 # keep the dump slot inert
+    return out if contrib.ndim == 2 else out[:, 0]
 
 
 def broadcast_configs(damping, iters) -> tuple[np.ndarray, np.ndarray]:
@@ -147,7 +162,7 @@ def pagerank_sell(
     *,
     damping=0.85,
     iters=20,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Full PageRank over bucketed SELL reverse adjacency, batched configs.
 
@@ -160,8 +175,8 @@ def pagerank_sell(
     """
     scalar = np.ndim(damping) == 0 and np.ndim(iters) == 0
     n = n_nodes
-    dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    if scalar:                                # single-column fast path
+    dtype = float_dtype()
+    if scalar:
         rank = jnp.full((n,), 1.0 / n, dtype)
         deg = out_degree.astype(dtype)
         zero = jnp.zeros((1,), dtype)
@@ -205,7 +220,7 @@ def pagerank(
     iters: int = 20,
     vl: int = 256,
     n_real: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Full PageRank: ``iters`` power steps over the reverse adjacency.
 
@@ -221,7 +236,7 @@ def pagerank(
         radj = jnp.pad(radj, ((0, pad), (0, 0)), constant_values=PAD)
         out_degree = jnp.pad(out_degree, (0, pad))
     n_pad = radj.shape[0]
-    dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    dtype = float_dtype()
     real = jnp.arange(n_pad) < n
     rank = jnp.where(real, 1.0 / n, 0.0).astype(dtype)
     deg = out_degree.astype(dtype)

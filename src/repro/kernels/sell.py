@@ -36,7 +36,7 @@ def spmv_sell(
     *,
     n_rows: int,
     w_block: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """y = A @ x over width-bucketed SELL slabs; returns y of shape (n_rows,).
 

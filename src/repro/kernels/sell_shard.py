@@ -32,15 +32,19 @@ device.  CPU CI builds an N-device mesh with
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from repro.compat import MeshContext, concrete_mesh, jaxshim, make_mesh
-from repro.compat.jaxshim import P
+from repro.compat.jaxshim import NamedSharding, P
 from repro.graphs.gen import ShardedGraphSlabs
 from repro.kernels import sell_core
+from repro.kernels.backend import float_dtype, resolve_interpret
 from repro.kernels.bfs import INF, _bfs_sell_step_kernel
 from repro.kernels.pagerank import _pr_sell_step_kernel, broadcast_configs
 from repro.sparse.formats import SellSlabs, ShardedSlabs
@@ -53,6 +57,7 @@ __all__ = [
     "bfs_sell_sharded",
     "device_mesh",
     "pagerank_sell_sharded",
+    "place",
     "spmm_sell_rhs_sharded",
     "spmm_sell_sharded",
 ]
@@ -80,19 +85,10 @@ def device_mesh(n_devices: int, devices=None) -> MeshContext:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """compat ``shard_map`` with output-replication checking off.
-
-    The graph combines produce replicated outputs *via collectives*, which
-    the static rep checker cannot always prove; the disabling kwarg also
-    renamed across jax versions (``check_rep`` -> ``check_vma``), so probe
-    both spellings before falling back to the default-checked call.
-    """
-    for kw in ({"check_rep": False}, {"check_vma": False}):
-        try:
-            return jaxshim.shard_map(f, mesh, in_specs, out_specs, **kw)
-        except TypeError:
-            continue
-    return jaxshim.shard_map(f, mesh, in_specs, out_specs)
+    """compat ``shard_map`` with output-replication checking off: the
+    graph combines produce replicated outputs *via collectives*, which the
+    static checker cannot always prove."""
+    return jaxshim.shard_map(f, mesh, in_specs, out_specs, check_vma=False)
 
 
 def _as_mesh(mesh):
@@ -100,6 +96,36 @@ def _as_mesh(mesh):
     if isinstance(mesh, MeshContext):
         mesh = mesh.mesh
     return concrete_mesh(mesh)
+
+
+def place(sharded, mesh):
+    """Put each shard's slabs on its own device, once.
+
+    ``sharded`` is a :class:`ShardedSlabs` or :class:`ShardedGraphSlabs`
+    of host arrays stacked along the device axis; the returned copy holds
+    every per-shard array as a ``NamedSharding`` over the mesh axis, so
+    shard d's block lives on device d and a call stages nothing through
+    one device.  Without a concrete multi-device mesh it is returned
+    unchanged (the serial path reads host arrays).
+    """
+    m, axis = _mesh_axis(mesh, sharded.n_shards)
+    if m is None:
+        return sharded
+    on_shards = NamedSharding(m, P(axis))
+
+    def put(arrays):
+        return tuple(jax.device_put(a, on_shards) for a in arrays)
+
+    if isinstance(sharded, ShardedGraphSlabs):
+        return dataclasses.replace(
+            sharded, bucket_adj=put(sharded.bucket_adj),
+            bucket_nodes=put(sharded.bucket_nodes))
+    return dataclasses.replace(
+        sharded, bucket_cols=put(sharded.bucket_cols),
+        bucket_vals=put(sharded.bucket_vals),
+        bucket_rows=put(sharded.bucket_rows),
+        col_starts=jax.device_put(
+            np.asarray(sharded.col_starts, np.int32), on_shards))
 
 
 def _mesh_axis(mesh, n_shards: int):
@@ -131,7 +157,7 @@ def spmm_sell_sharded(
     mesh=None,
     w_block: int = 8,
     k_block: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Y = A @ X with A row-partitioned across a device mesh.
 
@@ -142,34 +168,48 @@ def spmm_sell_sharded(
     boundary-column gather).  Row ranges are disjoint, so the per-device
     outputs concatenate; no reduction collective runs.  Without a concrete
     multi-device mesh the same per-shard program runs serially, so results
-    are identical at any device count.
+    are identical at any device count.  Slabs already :func:`place`-d on
+    the mesh are used where they live, and the program compiles once per
+    operand layout and RHS shape.
     """
-    x = jnp.asarray(x)
+    m, axis = _mesh_axis(mesh, sharded.n_shards)
+    return _row_sharded_spmm(
+        tuple(jnp.asarray(b) for b in sharded.bucket_cols),
+        tuple(jnp.asarray(b) for b in sharded.bucket_vals),
+        tuple(jnp.asarray(b) for b in sharded.bucket_rows),
+        jnp.asarray(sharded.col_starts, jnp.int32), jnp.asarray(x),
+        mesh=m, axis=axis, row_counts=tuple(int(r) for r in
+                                            sharded.row_counts),
+        window=int(sharded.window_cols), w_block=w_block, k_block=k_block,
+        interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "axis", "row_counts", "window", "w_block", "k_block",
+    "interpret"))
+def _row_sharded_spmm(cols_t, vals_t, rows_t, starts, x, *, mesh, axis,
+                      row_counts, window, w_block, k_block, interpret):
     k = int(x.shape[1])
-    nsh = sharded.n_shards
-    m, axis = _mesh_axis(mesh, nsh)
+    nsh = len(row_counts)
     kp = sell_core.k_tile_for(k, k_block)
     xk = sell_core.padded_k(k, k_block)
     if k != xk:
         x = jnp.pad(x, ((0, 0), (0, xk - k)))
-    win = int(sharded.window_cols)
-    rows_max = sharded.rows_max
-    dtype = sharded.bucket_vals[0].dtype if sharded.bucket_vals else x.dtype
-    cols_t = tuple(jnp.asarray(b) for b in sharded.bucket_cols)
-    vals_t = tuple(jnp.asarray(b) for b in sharded.bucket_vals)
-    rows_t = tuple(jnp.asarray(b) for b in sharded.bucket_rows)
-    starts = jnp.asarray(sharded.col_starts, jnp.int32)
+    rows_max = max(row_counts)
+    dtype = vals_t[0].dtype if vals_t else x.dtype
+    lanes = sell_core.lane_width(cols_t[0].shape[-1]) if cols_t else 1
 
     def local(cols, vals, rows, start, xg):
-        xw = jax.lax.dynamic_slice_in_dim(xg, start, win, axis=0)
+        xw = jax.lax.dynamic_slice_in_dim(xg, start, window, axis=0)
+        xt = sell_core.lane_table(xw.astype(dtype), lanes)
         y = jnp.zeros((rows_max + 1, xk), dtype)   # +1 local dump slot
         for cb, vb, rb in zip(cols, vals, rows):
             yb = sell_core.spmm_bucket(
-                cb, vb, xw, w_block=w_block, k_tile=kp, interpret=interpret)
+                cb, vb, xt, w_block=w_block, k_tile=kp, interpret=interpret)
             y = y.at[rb.reshape(-1)].set(yb)
         return y
 
-    if m is None:
+    if mesh is None:
         out = jnp.stack([
             local(tuple(b[d] for b in cols_t), tuple(b[d] for b in vals_t),
                   tuple(b[d] for b in rows_t), starts[d], x)
@@ -182,13 +222,13 @@ def spmm_sell_sharded(
                 tuple(b[0] for b in rows), st[0], xg)[None]
 
         out = _shard_map(
-            body, m,
+            body, mesh,
             (P(axis), P(axis), P(axis), P(axis), P()),
             P(axis),
         )(cols_t, vals_t, rows_t, starts, x)
 
-    pieces = [out[d, : int(sharded.row_counts[d])] for d in range(nsh)]
-    return jnp.concatenate(pieces, axis=0)[: sharded.n_rows, :k]
+    pieces = [out[d, :row_counts[d]] for d in range(nsh)]
+    return jnp.concatenate(pieces, axis=0)[:sum(row_counts), :k]
 
 
 def spmm_sell_rhs_sharded(
@@ -198,7 +238,7 @@ def spmm_sell_rhs_sharded(
     mesh=None,
     w_block: int = 8,
     k_block: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Y = A @ X with the RHS *columns* sharded: the k ≫ k_block path.
 
@@ -209,42 +249,52 @@ def spmm_sell_rhs_sharded(
     whole RHS tiles.  Degrades to plain :func:`sell_core.spmm_sell`
     without a concrete multi-device mesh.
     """
-    x = jnp.asarray(x)
-    k = int(x.shape[1])
+    interpret = resolve_interpret(interpret)
     m = _as_mesh(mesh)
     args = (
         tuple(jnp.asarray(b) for b in slabs.bucket_cols),
         tuple(jnp.asarray(b) for b in slabs.bucket_vals),
         tuple(jnp.asarray(b) for b in slabs.bucket_rows),
+        jnp.asarray(x),
     )
     if m is None:
         return sell_core.spmm_sell(
-            *args, x, n_rows=slabs.n_rows, w_block=w_block,
+            *args, n_rows=slabs.n_rows, w_block=w_block,
             k_block=k_block, interpret=interpret)
     shape = dict(m.shape)
     if len(shape) != 1:
         raise ValueError(
             f"sharded SELL execution expects a 1-D mesh, got axes {shape}")
-    axis, n = next(iter(shape.items()))
-    n = int(n)
+    return _rhs_sharded_spmm(
+        *args, mesh=m, axis=next(iter(shape)), n_rows=slabs.n_rows,
+        w_block=w_block, k_block=k_block, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "axis", "n_rows", "w_block", "k_block", "interpret"))
+def _rhs_sharded_spmm(cols_t, vals_t, rows_t, x, *, mesh, axis, n_rows,
+                      w_block, k_block, interpret):
+    k = int(x.shape[1])
+    n = int(mesh.shape[axis])
     kp = sell_core.k_tile_for(k, k_block)
     xk = n * kp * (-(-k // (n * kp)))          # whole k tiles per device
     if k != xk:
         x = jnp.pad(x, ((0, 0), (0, xk - k)))
-    n_rows = slabs.n_rows
-    dtype = args[1][0].dtype if args[1] else x.dtype
+    dtype = vals_t[0].dtype if vals_t else x.dtype
+    lanes = sell_core.lane_width(cols_t[0].shape[-1]) if cols_t else 1
 
     def body(cols, vals, rows, xb):
         y = jnp.zeros((n_rows + 1, xb.shape[1]), dtype)
+        xt = sell_core.lane_table(xb.astype(dtype), lanes)
         for cb, vb, rb in zip(cols, vals, rows):
             yb = sell_core.spmm_bucket(
-                cb, vb, xb, w_block=w_block, k_tile=kp, interpret=interpret)
+                cb, vb, xt, w_block=w_block, k_tile=kp, interpret=interpret)
             y = y.at[rb.reshape(-1)].set(yb)
         return y
 
     out = _shard_map(
-        body, m, (P(), P(), P(), P(None, axis)), P(None, axis),
-    )(*args, x)
+        body, mesh, (P(), P(), P(), P(None, axis)), P(None, axis),
+    )(cols_t, vals_t, rows_t, x)
     return out[:n_rows, :k]
 
 
@@ -253,44 +303,56 @@ def spmm_sell_rhs_sharded(
 # ---------------------------------------------------------------------------
 
 
-def _graph_step_fn(sg: ShardedGraphSlabs, mesh, kernel, combine_serial,
-                   combine_name, interpret: bool):
-    """Build ``step(state_tuple_resident, out_init) -> combined state``.
+def _graph_step_fn(sg: ShardedGraphSlabs, mesh, kernel, combine: str,
+                   interpret: bool | None):
+    """Build ``step(state, scalars, out_init) -> combined state``.
 
     The per-device program is :func:`sell_core.bucketed_node_step` over the
     shard's buckets — identical to the single-device drivers — followed by
-    the cross-device combine.  Serially (no concrete mesh) the same
-    combine folds over shards, so both paths compute the same values.
+    the cross-device combine (``combine`` is ``"pmin"`` or ``"psum"``).
+    Serially (no concrete mesh) the matching element-wise op folds over
+    shards, so both paths compute the same values.  The step compiles once
+    per layout and state shape, not once per level.
     """
-    nsh = sg.n_shards
-    m, axis = _mesh_axis(mesh, nsh)
+    m, axis = _mesh_axis(mesh, sg.n_shards)
     adj_t = tuple(jnp.asarray(b) for b in sg.bucket_adj)
     nodes_t = tuple(jnp.asarray(b) for b in sg.bucket_nodes)
+    interpret = resolve_interpret(interpret)
 
-    if m is None:
-        def step(resident, out_init):
-            acc = None
-            for d in range(nsh):
-                part = sell_core.bucketed_node_step(
-                    kernel, tuple(b[d] for b in adj_t),
-                    tuple(b[d] for b in nodes_t), resident, out_init,
-                    interpret=interpret)
-                acc = part if acc is None else combine_serial(acc, part)
-            return acc
-        return step
-
-    def body(adjs, nodeses, resident, out_init):
-        part = sell_core.bucketed_node_step(
-            kernel, tuple(b[0] for b in adjs), tuple(b[0] for b in nodeses),
-            resident, out_init, interpret=interpret)
-        return getattr(jax.lax, combine_name)(part, axis)
-
-    def step(resident, out_init):
-        return _shard_map(
-            body, m, (P(axis), P(axis), P(), P()), P(),
-        )(adj_t, nodes_t, resident, out_init)
+    def step(state, scalars, out_init):
+        return _node_step_program(
+            adj_t, nodes_t, state, scalars, out_init, kernel=kernel, mesh=m,
+            axis=axis, combine=combine, interpret=interpret)
 
     return step
+
+
+_SERIAL_COMBINE = {"pmin": jnp.minimum, "psum": jnp.add}
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kernel", "mesh", "axis", "combine", "interpret"))
+def _node_step_program(adj_t, nodes_t, state, scalars, out_init, *, kernel,
+                       mesh, axis, combine, interpret):
+    if mesh is None:
+        acc = None
+        for d in range(adj_t[0].shape[0] if adj_t else 0):
+            part = sell_core.bucketed_node_step(
+                kernel, tuple(b[d] for b in adj_t),
+                tuple(b[d] for b in nodes_t), state, scalars, out_init,
+                interpret=interpret)
+            acc = part if acc is None else _SERIAL_COMBINE[combine](acc, part)
+        return out_init if acc is None else acc
+
+    def body(adjs, nodeses, state, scalars, out_init):
+        part = sell_core.bucketed_node_step(
+            kernel, tuple(b[0] for b in adjs), tuple(b[0] for b in nodeses),
+            state, scalars, out_init, interpret=interpret)
+        return getattr(jax.lax, combine)(part, axis)
+
+    return _shard_map(
+        body, mesh, (P(axis), P(axis), P(), P(), P()), P(),
+    )(adj_t, nodes_t, state, scalars, out_init)
 
 
 def bfs_sell_sharded(
@@ -299,7 +361,7 @@ def bfs_sell_sharded(
     *,
     mesh=None,
     max_levels: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """BFS over node-partitioned SELL adjacency: frontier union by ``pmin``.
 
@@ -311,23 +373,18 @@ def bfs_sell_sharded(
     (n,), k sources -> (n, k)).
     """
     n = sg.n_nodes
-    scalar = np.ndim(source) == 0
-    if scalar:
-        dist = jnp.full((n + 1,), INF, jnp.int32).at[int(source)].set(0)
-    else:
-        sources = np.asarray(source, np.int64)
-        k = len(sources)
-        dist = jnp.full((n + 1, k), INF, jnp.int32)
-        dist = dist.at[jnp.asarray(sources), jnp.arange(k)].set(0)
-    step = _graph_step_fn(
-        sg, mesh, _bfs_sell_step_kernel, jnp.minimum, "pmin", interpret)
+    sources = np.atleast_1d(np.asarray(source, np.int64))
+    k = len(sources)
+    dist = jnp.full((n + 1, k), INF, jnp.int32)
+    dist = dist.at[jnp.asarray(sources), jnp.arange(k)].set(0)
+    step = _graph_step_fn(sg, mesh, _bfs_sell_step_kernel, "pmin", interpret)
     for level in range(1, (max_levels or n) + 1):
-        new = step((dist, jnp.array([level], jnp.int32)), dist)
+        new = step(dist, jnp.array([level], jnp.int32), dist)
         new = new.at[-1].set(INF)              # keep the dump slot inert
         if bool(jnp.all(new == dist)):
             break
         dist = new
-    return dist[:n]
+    return dist[:n, 0] if np.ndim(source) == 0 else dist[:n]
 
 
 def pagerank_sell_sharded(
@@ -337,7 +394,7 @@ def pagerank_sell_sharded(
     mesh=None,
     damping=0.85,
     iters=20,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """PageRank over node-partitioned reverse adjacency: rank exchange by
     ``psum``.
@@ -350,26 +407,12 @@ def pagerank_sell_sharded(
     """
     n = sg.n_nodes
     scalar = np.ndim(damping) == 0 and np.ndim(iters) == 0
-    dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    step = _graph_step_fn(
-        sg, mesh, _pr_sell_step_kernel, jnp.add, "psum", interpret)
-    deg0 = jnp.asarray(out_degree).astype(dtype)
-    if scalar:
-        rank = jnp.full((n,), 1.0 / n, dtype)
-        zero = jnp.zeros((1,), dtype)
-        for _ in range(int(iters)):
-            contrib = jnp.where(deg0 > 0, rank / jnp.maximum(deg0, 1), 0.0)
-            dangling = jnp.sum(jnp.where(deg0 == 0, rank, 0.0))
-            consts = jnp.stack(
-                [(1.0 - damping) / n, damping, dangling / n]).astype(dtype)
-            state = jnp.concatenate([contrib, zero])
-            new = step((state, consts), jnp.zeros_like(state))
-            rank = new.at[-1].set(0.0)[:n]
-        return rank
+    dtype = float_dtype()
+    step = _graph_step_fn(sg, mesh, _pr_sell_step_kernel, "psum", interpret)
     dampings, iters_arr = broadcast_configs(damping, iters)
     k = len(dampings)
     rank = jnp.full((n, k), 1.0 / n, dtype)
-    deg = deg0[:, None]
+    deg = jnp.asarray(out_degree).astype(dtype)[:, None]
     d = jnp.asarray(dampings, dtype)
     zero_row = jnp.zeros((1, k), dtype)
     for t in range(1, int(iters_arr.max()) + 1):
@@ -377,8 +420,8 @@ def pagerank_sell_sharded(
         dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0), axis=0)
         consts = jnp.stack([(1.0 - d) / n, d, dangling / n]).astype(dtype)
         state = jnp.concatenate([contrib, zero_row])
-        new = step((state, consts), jnp.zeros_like(state))
+        new = step(state, consts, jnp.zeros_like(state))
         new = new.at[-1].set(0.0)[:n]
         active = jnp.asarray(t <= iters_arr)
         rank = jnp.where(active[None, :], new, rank)
-    return rank
+    return rank[:, 0] if scalar else rank

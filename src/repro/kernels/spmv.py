@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 PAD = -1
 
 
@@ -48,7 +50,7 @@ def spmv_ell(
     x: jnp.ndarray,
     *,
     w_block: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """y = A @ x for A in slice-transposed ELLPACK (n_slices, W, C).
 
@@ -73,6 +75,6 @@ def spmv_ell(
         ],
         out_specs=pl.BlockSpec((1, c), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_slices, c), vals.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cols, vals, x)
     return out.reshape(-1)

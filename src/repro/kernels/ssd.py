@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _ssd_fused_kernel(xd_ref, ad_ref, b_ref, c_ref, y_ref, fs_ref, *,
                       chunk: int, n_chunks: int):
@@ -61,7 +63,7 @@ def ssd_fused(
     C: jnp.ndarray,     # (b, l, g, n)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused scan.  Returns (y (b,l,h,p), final_state (b,h,p,n))."""
     b, l, h, p = xd.shape
@@ -92,6 +94,6 @@ def ssd_fused(
             jax.ShapeDtypeStruct((b, h, l, p), xd.dtype),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.promote_types(xd.dtype, jnp.float32)),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xbh, abh, bbh, cbh)
     return y.transpose(0, 2, 1, 3), fs

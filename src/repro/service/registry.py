@@ -28,9 +28,21 @@ from repro.analysis.preflight import (
 from repro.core.autotune import SellTuneResult
 from repro.core.sdv import MachineParams, tpu_v5e_machine
 from repro.obs import MetricsRegistry, Stopwatch
-from repro.graphs.gen import EllpackGraph, graph_to_sell_slabs
+from repro.graphs.gen import (
+    EllpackGraph,
+    graph_to_sell_slabs,
+    in_degree,
+    shard_graph_slabs,
+)
+from repro.kernels.backend import float_dtype
 from repro.service.tunecache import OperandSignature, TuneCache, operand_signature
-from repro.sparse.formats import CSRMatrix, SellSlabs, pow2_ceil, to_csr
+from repro.sparse.formats import (
+    CSRMatrix,
+    SellSlabs,
+    pow2_ceil,
+    to_csr,
+    widest_k_tile,
+)
 
 
 @dataclasses.dataclass
@@ -179,6 +191,11 @@ class KernelRegistry:
 
         sw = Stopwatch().start()
         csr = to_csr(matrix) if not isinstance(matrix, CSRMatrix) else matrix
+        # with x64 off (the TPU has no float64) wider values pack as
+        # float32: pack, key and plan the values the kernels will read
+        dtype = np.dtype(float_dtype())
+        if csr.data.dtype.itemsize > dtype.itemsize:
+            csr = dataclasses.replace(csr, data=csr.data.astype(dtype))
         sig = operand_signature(csr)
         before = self.cache.hits
         # pack_tuned owns the cached tune-and-pack sequence (key build,
@@ -201,22 +218,25 @@ class KernelRegistry:
         # corrupt pack or a stale/poisoned cached tune is rejected here
         # with a structured LaunchPlanError, never served
         op.slab_meta = SlabMeta.from_slabs(slabs, check_bounds=True)
+        # plans are priced at the widest RHS tile any coalesced group runs
+        k = widest_k_tile(tuned.k_block)
         if self.n_devices > 1:
+            from repro.kernels.sell_shard import place
             from repro.sparse.formats import shard_slabs
 
-            op.sharded = shard_slabs(slabs, self.n_devices)
+            # each shard's slabs go to its own device once, here
+            op.sharded = place(shard_slabs(slabs, self.n_devices), self.mesh)
             op.mode = "sharded"
             op.plans = {"spmv": plan_spmm_sell_sharded(
-                op.slab_meta, k=max(1, tuned.k_block),
+                op.slab_meta, k=k,
                 x_dtype=str(csr.data.dtype),
                 n_devices=self.n_devices,
                 w_block=tuned.w_block, k_block=tuned.k_block,
                 window_cols=op.sharded.window_cols,
             ).raise_if_invalid()}
-            op.device_arrays = _matrix_device_arrays(slabs)
             return self._admit(op, sw)
         resident = plan_spmm_sell(
-            op.slab_meta, k=max(1, tuned.k_block),
+            op.slab_meta, k=k,
             x_dtype=str(csr.data.dtype),
             w_block=tuned.w_block, k_block=tuned.k_block,
         )
@@ -230,7 +250,7 @@ class KernelRegistry:
             # poisoned/stale cached tune is rejected here exactly as before.
             op.mode = "stream"
             op.plans = {"spmv": plan_spmm_sell_stream(
-                op.slab_meta, k=max(1, tuned.k_block),
+                op.slab_meta, k=k,
                 x_dtype=str(csr.data.dtype),
                 w_block=tuned.w_block, k_block=tuned.k_block,
                 col_tile=tuned.col_tile, row_tile=tuned.row_tile,
@@ -242,30 +262,47 @@ class KernelRegistry:
         """Pack + tune a graph for BFS/PageRank serving.
 
         Both pull-style kernels consume the *reverse* adjacency, so the
-        registry packs ``graph.transpose()`` into SELL slabs, tuned on the
-        in-degree distribution (the row-length law of the pull traffic).
-        Graph kernels always serve float64 (the x64 path), so the cache
-        key is fixed to it.
+        registry packs the in-neighbours into SELL slabs straight from the
+        edge list (:func:`graph_to_sell_slabs` with ``reverse=True``),
+        tuned on the in-degree distribution (the row-length law of the pull
+        traffic).  Under a multi-device mesh the same packer builds the
+        node-partitioned layout instead — one pack either way.  The tuned
+        layout is preflighted from its metadata before anything is packed,
+        so a graph whose launch plans fail is refused without building its
+        slabs.  The cache key records the float dtype the PageRank state is
+        held in on the device.
         """
-        dtype = "float64"
-        from repro.kernels.ops import tune_and_pack
+        dtype = str(np.dtype(float_dtype()))
+        from repro.kernels.ops import _sharded_graph_meta, tune_and_pack
 
         sw = Stopwatch().start()
         sig = operand_signature(graph)
+        # the device count keys the packed-layout memo: one graph packs to
+        # different slabs on one device and on a mesh
         key = self.cache.sell_key("graph", sig, device=self.device,
-                                  dtype=dtype, machine=self.machine)
+                                  dtype=dtype, machine=self.machine,
+                                  n_devices=self.n_devices)
         before = self.cache.hits
-        rgraph = graph.transpose()
-        in_deg = (rgraph.adj != -1).sum(axis=1).astype(np.int64)
+        in_deg = in_degree(graph)
+
+        def pack(tuned):
+            meta = _graph_layout_meta(in_deg, tuned.c, tuned.sigma)
+            plan_bfs_sell(meta).raise_if_invalid()
+            plan_pagerank_sell(meta, dtype=dtype).raise_if_invalid()
+            if self.n_devices > 1:
+                return shard_graph_slabs(
+                    graph, c=tuned.c, n_shards=self.n_devices,
+                    sigma=tuned.sigma, reverse=True)
+            return graph_to_sell_slabs(graph, c=tuned.c, sigma=tuned.sigma,
+                                       reverse=True)
+
         # both pull-style kernels share the layout; a pagerank (or bfs)
         # campaign hint narrows the sweep for either — tune_and_pack owns
         # the hinted-vs-full-grid key protocol and the packed-slab memo
         hinted = (self.cache.candidate_vls_for("pagerank", self.machine.name)
                   or self.cache.candidate_vls_for("bfs", self.machine.name))
         slabs, tuned = tune_and_pack(
-            in_deg,
-            lambda t: graph_to_sell_slabs(rgraph, c=t.c, sigma=t.sigma),
-            n_cols=graph.n_nodes, machine=self.machine,
+            in_deg, pack, n_cols=graph.n_nodes, machine=self.machine,
             candidates_c=hinted, cache=self.cache, base_key=key,
         )
         op = RegisteredOperand(
@@ -273,23 +310,23 @@ class KernelRegistry:
             slabs=slabs, n=graph.n_nodes,
             tune_was_cached=self.cache.hits > before,
         )
-        op.slab_meta = SlabMeta.from_slabs(slabs, check_bounds=True)
         if self.n_devices > 1:
-            from repro.graphs.gen import shard_graph_slabs
-            from repro.kernels.ops import _sharded_graph_meta
+            from repro.kernels.sell_shard import place
 
-            op.sharded = shard_graph_slabs(
-                rgraph, c=tuned.c, n_shards=self.n_devices,
-                sigma=tuned.sigma)
+            op.sharded = place(slabs, self.mesh)
             op.mode = "sharded"
             # per-device plan: each device runs slices_per_shard slices of
             # every union bucket against the full replicated state
-            op.slab_meta = _sharded_graph_meta(op.sharded)
+            op.slab_meta = _sharded_graph_meta(slabs, check_bounds=True)
+        else:
+            op.slab_meta = SlabMeta.from_slabs(slabs, check_bounds=True)
         op.plans = {
             "bfs": plan_bfs_sell(op.slab_meta).raise_if_invalid(),
-            "pagerank": plan_pagerank_sell(op.slab_meta).raise_if_invalid(),
+            "pagerank": plan_pagerank_sell(
+                op.slab_meta, dtype=dtype).raise_if_invalid(),
         }
-        op.device_arrays = _graph_device_arrays(slabs, graph)
+        op.device_arrays = _graph_device_arrays(
+            None if op.sharded is not None else slabs, graph)
         return self._admit(op, sw)
 
     def register_fft(self, name: str, n: int) -> RegisteredOperand:
@@ -301,16 +338,17 @@ class KernelRegistry:
         sw = Stopwatch().start()
         if n & (n - 1) or n < 2:
             raise ValueError(f"fft length must be a power of two >= 2, got {n}")
-        wre, wim = fft_twiddles(n, np.float64)
+        dtype = float_dtype()
+        wre, wim = fft_twiddles(n, dtype)
         op = RegisteredOperand(name=name, kind="fft", signature=None, n=n)
-        op.plans = {
-            "fft": plan_fft_stockham(n, batch=8).raise_if_invalid()}
+        op.plans = {"fft": plan_fft_stockham(
+            n, batch=8, dtype=str(np.dtype(dtype))).raise_if_invalid()}
         op.device_arrays = {"wre": jnp.asarray(wre), "wim": jnp.asarray(wim)}
         return self._admit(op, sw)
 
     def register_moe(self, name: str, *, n_tokens: int, n_slots: int,
                      d_model: int, top_k: int, c: int = 32,
-                     dtype: str = "float64") -> RegisteredOperand:
+                     dtype: str | None = None) -> RegisteredOperand:
         """Admit an LM engine's MoE dispatch traffic class.
 
         Unlike matrices and graphs, the operand itself is transient — the
@@ -326,6 +364,7 @@ class KernelRegistry:
         sw = Stopwatch().start()
         if top_k < 1:
             raise ValueError(f"top_k must be >= 1, got {top_k}")
+        dtype = dtype or str(np.dtype(float_dtype()))
         w = pow2_ceil(max(int(top_k), 1))
         meta = SlabMeta(
             kind="matrix", c=int(c), widths=(w,),
@@ -355,11 +394,28 @@ def _matrix_device_arrays(slabs: SellSlabs) -> dict:
     }
 
 
+def _graph_layout_meta(in_deg: np.ndarray, c: int, sigma: int) -> SlabMeta:
+    """Launch metadata of the reverse-graph slabs ``graph_to_sell_slabs``
+    would pack at (c, sigma), from the in-degrees alone."""
+    from repro.sparse.formats import next_pow2, sigma_sort_order, slice_widths
+
+    widths = next_pow2(slice_widths(in_deg, sigma_sort_order(in_deg, sigma),
+                                    c))
+    uniq, counts = np.unique(widths, return_counts=True)
+    n = len(in_deg)
+    return SlabMeta(
+        kind="graph", c=int(c), widths=tuple(int(w) for w in uniq),
+        n_slices=tuple(int(k) for k in counts), n_rows=n, n_cols=n,
+        val_dtype=None, idx_dtype="int32")
+
+
 def _graph_device_arrays(slabs, graph: EllpackGraph) -> dict:
+    """Out-degrees for PageRank, plus the single-device adjacency slabs
+    (``slabs=None`` for a sharded operand, whose slabs live on the mesh)."""
     import jax.numpy as jnp
 
-    return {
-        "adj": tuple(jnp.asarray(a) for a in slabs.bucket_adj),
-        "nodes": tuple(jnp.asarray(m) for m in slabs.bucket_nodes),
-        "out_degree": jnp.asarray(graph.out_degree.astype(np.float64)),
-    }
+    arrays = {"out_degree": jnp.asarray(graph.out_degree, float_dtype())}
+    if slabs is not None:
+        arrays["adj"] = tuple(jnp.asarray(a) for a in slabs.bucket_adj)
+        arrays["nodes"] = tuple(jnp.asarray(m) for m in slabs.bucket_nodes)
+    return arrays

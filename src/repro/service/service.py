@@ -65,7 +65,7 @@ from repro.analysis.preflight import (
 from repro.kernels.execspec import ExecSpec
 from repro.service.registry import KernelRegistry, RegisteredOperand
 from repro.serve.slots import SlotLoop
-from repro.sparse.formats import pow2_ceil
+from repro.sparse.formats import pow2_ceil, widest_k_tile
 
 OPS = ("spmv", "bfs", "pagerank", "fft", "moe_dispatch")
 
@@ -195,7 +195,7 @@ class KernelService(SlotLoop[KernelRequest]):
                  metrics: MetricsRegistry | None = None,
                  tracer: Tracer | None = None):
         super().__init__(n_slots)
-        from repro.kernels.ops import default_interpret
+        from repro.kernels.backend import resolve_interpret
 
         if max_queue is not None and max_queue < 1:
             raise ValueError(
@@ -203,7 +203,7 @@ class KernelService(SlotLoop[KernelRequest]):
                 f"{max_queue}: a zero-capacity queue rejects every submit "
                 "and the reject-then-step retry pattern would spin forever")
         self.registry = registry
-        self.interpret = default_interpret() if interpret is None else interpret
+        self.interpret = resolve_interpret(interpret)
         self.max_queue = max_queue
         self._next_rid = 0
         self._by_rid: dict[int, KernelRequest] = {}
@@ -373,31 +373,37 @@ class KernelService(SlotLoop[KernelRequest]):
         plans: dict[str, LaunchPlan] = {}
         if record.kind == "matrix" and record.slab_meta is not None:
             tuned = record.tuned
+            # worst case: the widest RHS tile any coalesced group runs
+            k = widest_k_tile(tuned.k_block)
             if record.mode == "sharded":
                 plans["spmv"] = plan_spmm_sell_sharded(
-                    record.slab_meta, k=max(1, tuned.k_block),
+                    record.slab_meta, k=k,
                     x_dtype=record.slab_meta.val_dtype,
                     n_devices=self.registry.n_devices,
                     w_block=tuned.w_block, k_block=tuned.k_block,
                     window_cols=record.sharded.window_cols)
             elif record.mode == "stream":
                 plans["spmv"] = plan_spmm_sell_stream(
-                    record.slab_meta, k=max(1, tuned.k_block),
+                    record.slab_meta, k=k,
                     x_dtype=record.slab_meta.val_dtype,
                     w_block=tuned.w_block, k_block=tuned.k_block,
                     col_tile=tuned.col_tile, row_tile=tuned.row_tile)
             else:
                 plans["spmv"] = plan_spmm_sell(
-                    record.slab_meta, k=max(1, tuned.k_block),
+                    record.slab_meta, k=k,
                     x_dtype=record.slab_meta.val_dtype,
                     w_block=tuned.w_block, k_block=tuned.k_block)
         elif record.kind == "graph" and record.slab_meta is not None:
             # worst case: a full coalesced group, pow2-padded
             k = pow2_ceil(max(1, self.n_slots))
             plans["bfs"] = plan_bfs_sell(record.slab_meta, k=k)
-            plans["pagerank"] = plan_pagerank_sell(record.slab_meta, k=k)
+            plans["pagerank"] = plan_pagerank_sell(
+                record.slab_meta, k=k,
+                dtype=str(record.device_arrays["out_degree"].dtype))
         elif record.kind == "fft":
-            plans["fft"] = plan_fft_stockham(record.n, batch=8)
+            plans["fft"] = plan_fft_stockham(
+                record.n, batch=8,
+                dtype=str(record.device_arrays["wre"].dtype))
         elif record.kind == "moe" and record.slab_meta is not None:
             m = record.moe
             plans["moe_dispatch"] = plan_moe_dispatch(
@@ -567,11 +573,12 @@ class KernelService(SlotLoop[KernelRequest]):
 
         arrs, tuned = operand.device_arrays, operand.tuned
         n_cols = operand.n_cols
+        dtype = np.dtype(operand.slab_meta.val_dtype)
 
         def check(req):
             # JAX clamps out-of-bounds gathers, so a wrong-sized x would
             # return garbage as a "success" — validate explicitly
-            x = np.asarray(req.payload, np.float64)
+            x = np.asarray(req.payload, dtype)
             if x.shape != (n_cols,):
                 raise ValueError(f"x must have shape ({n_cols},), got {x.shape}")
             return x
@@ -714,13 +721,14 @@ class KernelService(SlotLoop[KernelRequest]):
         import jax.numpy as jnp
 
         n = operand.n
+        dtype = operand.device_arrays["wre"].dtype
 
         def check(req):
             if np.iscomplexobj(req.payload):
-                # float64 casting would silently drop the imaginary plane
+                # a real-dtype cast would silently drop the imaginary plane
                 raise TypeError("complex signals are not supported; "
                                 "pass split re/im planes")
-            sig = np.atleast_2d(np.asarray(req.payload, np.float64))
+            sig = np.atleast_2d(np.asarray(req.payload, dtype))
             if sig.ndim != 2:
                 raise ValueError(f"signal must be 1-D or 2-D (batch, n), "
                                  f"got shape {sig.shape}")

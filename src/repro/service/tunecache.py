@@ -380,7 +380,7 @@ class TuneCache:
     # -- keys --------------------------------------------------------------
     @staticmethod
     def sell_key(kernel: str, signature: OperandSignature | Any,
-                 device: str = "cpu", dtype: str = "float64",
+                 device: str = "cpu", dtype: str | None = None,
                  machine=None, n_devices: int = 1) -> str:
         """Cache key for a SELL layout decision.
 
@@ -393,7 +393,13 @@ class TuneCache:
         tune scores the busiest shard's row slice, not the whole operand,
         so single-device and N-device layouts must never share an entry
         (single-device keys keep their historical spelling unchanged).
+        ``dtype`` names the value dtype the device holds; ``None`` means
+        the backend's float dtype (float64 under x64, else float32).
         """
+        if dtype is None:
+            from repro.kernels.backend import float_dtype
+
+            dtype = str(np.dtype(float_dtype()))
         if not isinstance(signature, OperandSignature):
             signature = operand_signature(signature)
         mtag = machine_tag(machine) if machine is not None else "any-machine"

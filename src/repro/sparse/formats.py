@@ -321,6 +321,61 @@ def pow2_ceil(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
+#: sublanes of a TPU vreg: the second-minor dim of every kernel block must
+#: be a multiple of it or span the whole array axis
+SUBLANES = 8
+
+
+def k_tile_for(k: int, k_block: int) -> int:
+    """The RHS tile one SpMM grid cell processes.
+
+    A stack of at most 8 columns runs as one tile of ``pow2_ceil(k)``
+    columns, so its block spans the whole padded k axis; a wider stack
+    tiles by ``min(k_block, pow2_ceil(k))`` but never below 8.  Either way
+    the (k_tile, C) block meets the TPU (8, 128) tiling rule.  Both cases
+    are powers of two that divide ``pow2_ceil(k)``: a caller that
+    pow2-pads its stack (the service's ``_pow2_pad``) hands the core a k
+    the core never pads again (:func:`padded_k` is the identity on powers
+    of two).
+    """
+    p = pow2_ceil(max(int(k), 1))
+    if p <= SUBLANES:
+        return p
+    return max(SUBLANES, min(max(int(k_block), 1), p))
+
+
+def widest_k_tile(k_block: int) -> int:
+    """The widest RHS tile :func:`k_tile_for` gives a stack of any width
+    under this ``k_block``: a group of up to 8 columns runs whole even when
+    ``k_block`` is smaller, so a launch plan priced at this many columns
+    bounds every launch the operand can serve."""
+    return max(SUBLANES, int(k_block))
+
+
+def padded_k(k: int, k_block: int) -> int:
+    """The k the SpMM core actually runs: ``k`` rounded up to the k tile.
+
+    ``padded_k(pow2, k_block) == pow2`` for every pow2/k_block pair — the
+    ops boundary asserts this fixpoint so the pow2 padding applied by the
+    service and the tile padding applied by the core can never stack.
+    """
+    kp = k_tile_for(k, k_block)
+    return kp * -(-max(int(k), 1) // kp)
+
+
+def w_tile_for(width: int, w_block: int) -> int:
+    """Slab rows (W) one grid cell reads from a bucket of this width.
+
+    Buckets at most 8 wide are read whole; wider ones by
+    ``min(w_block, width)`` rows but never fewer than 8, so the (w, C)
+    block meets the TPU (8, 128) tiling rule.
+    """
+    width = int(width)
+    if width <= SUBLANES:
+        return width
+    return max(SUBLANES, min(max(int(w_block), 1), width))
+
+
 def next_pow2(x: np.ndarray) -> np.ndarray:
     """Element-wise next power of two (>= 1): the bucket width rounding
     (array form of :func:`pow2_ceil`)."""

@@ -1,7 +1,8 @@
 """Shared test config.
 
-x64 is enabled globally: the paper's kernels are double-precision and the
-Pallas kernels run in interpret mode on CPU.  Note: NO device-count flags are
+x64 is enabled globally: on the CPU the Pallas kernels run in interpret
+mode and are checked against float64 references (the chip serves float32,
+see ``chip_smoke.py``).  Note: NO device-count flags are
 set here — smoke tests and benches must see the single real CPU device; the
 512-device dry-run sets its XLA_FLAGS inside launch/dryrun.py (subprocess
 tests do the same).

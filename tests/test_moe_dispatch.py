@@ -154,7 +154,10 @@ def test_moe_forward_auto_falls_back_dense_under_jit():
         lambda p, xx: MOE.moe_forward(p, cfg, xx, spec=auto))(params, x)
     ref_out, ref_aux = MOE.moe_forward(params, cfg, x, spec=DENSE)
     np.testing.assert_allclose(np.asarray(jit_out), np.asarray(ref_out), **TOL)
-    np.testing.assert_allclose(float(jit_aux), float(ref_aux), **TOL)
+    # the aux loss is computed in float32 (models/moe.py), and jit and eager
+    # fuse its mean differently: they may differ by a few float32 ulps
+    np.testing.assert_allclose(float(jit_aux), float(ref_aux),
+                               rtol=1e-6, atol=1e-7)
 
 
 def test_moe_forward_forced_sell_under_jit_raises():

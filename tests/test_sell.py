@@ -265,3 +265,37 @@ def test_generators_scale_without_python_loops():
     m = F.random_csr(100_000, 100_000, 10.0, seed=0, skew=1.0)
     F.csr_to_sell_slabs(m, c=256)
     assert time.perf_counter() - t0 < 60.0
+
+
+@pytest.mark.parametrize("c,sigma", [(8, None), (64, 512), (256, 8192)])
+def test_reverse_sell_slabs_match_transpose_route(c, sigma):
+    """Packing the in-neighbours from the edge list builds exactly the
+    slabs of the degree-padded transpose route (same buckets, node maps
+    and in-neighbour order) without materializing the reverse graph."""
+    for g in (G.rmat_graph(1 << 12, avg_degree=16, seed=0),
+              G.random_graph(500, avg_degree=6, seed=3)):
+        want = G.graph_to_sell_slabs(g.transpose(), c=c, sigma=sigma)
+        got = G.graph_to_sell_slabs(g, c=c, sigma=sigma, reverse=True)
+        assert got.widths == want.widths and got.sigma == want.sigma
+        for a, b in zip(got.bucket_adj, want.bucket_adj):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(got.bucket_nodes, want.bucket_nodes):
+            np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(
+            G.in_degree(g), (g.transpose().adj != G.PAD).sum(axis=1))
+
+
+@pytest.mark.parametrize("n_shards", [1, 3, 4])
+def test_shard_graph_slabs_reverse_matches_transpose_route(n_shards):
+    """The sharded layout packs the in-neighbours from the edge list too:
+    identical to sharding the degree-padded reverse graph."""
+    g = G.rmat_graph(1 << 10, avg_degree=8, seed=4)
+    want = G.shard_graph_slabs(g.transpose(), c=16, n_shards=n_shards)
+    got = G.shard_graph_slabs(g, c=16, n_shards=n_shards, reverse=True)
+    assert got.widths == want.widths
+    np.testing.assert_array_equal(got.node_starts, want.node_starts)
+    np.testing.assert_array_equal(got.node_counts, want.node_counts)
+    for a, b in zip(got.bucket_adj + got.bucket_nodes,
+                    want.bucket_adj + want.bucket_nodes):
+        np.testing.assert_array_equal(a, b)
+    assert got.pad_factor >= 1.0

@@ -120,7 +120,7 @@ def test_graph_sharded_serial_matches_unsharded():
     g = G.random_graph(n_nodes=72, avg_degree=4, seed=7)
     ref_bfs = np.asarray(ops.bfs(g, 0, vl=16))
     ref_pr = np.asarray(ops.pagerank(g, iters=12, vl=16))
-    sg = G.shard_graph_slabs(g.transpose(), c=16, n_shards=3)
+    sg = G.shard_graph_slabs(g, c=16, n_shards=3, reverse=True)
     got_bfs = np.asarray(sell_shard.bfs_sell_sharded(sg, 0, mesh=None))
     got_pr = np.asarray(sell_shard.pagerank_sell_sharded(
         sg, np.asarray(g.out_degree, np.float64), iters=12, mesh=None))

@@ -224,10 +224,14 @@ def test_k_padding_pow2_fixpoint():
             assert sell_core.padded_k(k, kb) == k
             kt = sell_core.k_tile_for(k, kb)
             assert kt & (kt - 1) == 0 and k % kt == 0
-    # non-pow2 k pads exactly once, up to a multiple of the tile
-    assert sell_core.k_tile_for(3, 2) == 2
+    # non-pow2 k pads exactly once, up to a multiple of the tile; a stack
+    # of at most 8 columns is one whole-k tile, wider ones tile by >= 8
+    # rows so every (k_tile, C) block meets the TPU (8, 128) tiling rule
+    assert sell_core.k_tile_for(3, 2) == 4
     assert sell_core.padded_k(3, 2) == 4
     assert sell_core.padded_k(5, 8) == 8
+    assert sell_core.k_tile_for(32, 2) == 8
+    assert sell_core.padded_k(20, 2) == 24
 
 
 def test_tune_stream_only_fallback_and_tiles():
@@ -250,3 +254,26 @@ def test_tune_stream_only_fallback_and_tiles():
         k_block=tuned.k_block, col_tile=tuned.col_tile,
         row_tile=tuned.row_tile)
     assert plan.ok
+
+
+def test_registry_prices_matrix_plans_at_the_widest_group_tile():
+    """A group of up to 8 requests runs as one 8-column RHS tile whatever
+    ``k_block`` (``k_tile_for``), so registration and admission price that
+    tile.  A million-column operand whose tuned ``k_block`` fits the
+    resident schedule, but whose 8-column table does not, registers on the
+    streaming schedule, and the service admits it against that plan."""
+    csr = F.random_csr(4096, 1 << 20, 4.0, seed=9, dtype=np.float32)
+    registry = KernelRegistry()
+    rec = registry.register_matrix("wide", csr)
+    kb = rec.tuned.k_block
+    assert kb < F.SUBLANES
+    assert F.k_tile_for(8, kb) == F.widest_k_tile(kb) == F.SUBLANES
+    # the tuned tile alone would have passed: only the group tile rejects
+    assert plan_spmm_sell(rec.slab_meta, k=kb, x_dtype="float32",
+                          w_block=rec.tuned.w_block, k_block=kb).ok
+    assert not plan_spmm_sell(rec.slab_meta, k=F.SUBLANES, x_dtype="float32",
+                              w_block=rec.tuned.w_block, k_block=kb).ok
+    assert rec.mode == "stream"
+    plans = KernelService(registry, n_slots=8).plans()["wide"]
+    assert plans["spmv"]["kernel"] == "spmm_sell_stream"
+    assert plans["spmv"]["ok"]
