@@ -301,10 +301,7 @@ def bench_obs(load: int = 100, n_slots: int = 32, max_queue: int = 16,
 
     if trace_out:
         tracer.export_jsonl(trace_out)
-        chrome_out = os.path.splitext(trace_out)[0] + "_chrome.json"
-        tracer.export_chrome(chrome_out)
-        print(f"# wrote {trace_out} and {chrome_out} (load into "
-              "https://ui.perfetto.dev)")
+        print(f"# wrote {trace_out}")
     if metrics_out:
         svc_on.metrics.dump_json(metrics_out)
         print(f"# wrote {metrics_out}")
@@ -629,8 +626,7 @@ def main(argv=None) -> None:
                     help="hard-fail when tracing-on exceeds tracing-off "
                          "per-call wall by more than this fraction")
     ap.add_argument("--trace-out", default=None,
-                    help="export the tracing-on run's span JSONL (+ a "
-                         "_chrome.json Perfetto trace) here")
+                    help="export the tracing-on run's span JSONL here")
     ap.add_argument("--metrics-out", default=None,
                     help="export the tracing-on run's metrics snapshot here")
     args = ap.parse_args(argv)

@@ -11,8 +11,8 @@ by ``MetricsRegistry.dump_json``.  Renders:
 * span census: counts per span name, closed request roots, open (orphan)
   spans — the trace completeness surface;
 * request outcomes: ok / rejected / error roots, with rejection reasons;
-* stage breakdown: mean/max duration per span name (queued, preflight,
-  execute, launch);
+* stage breakdown: mean/max duration per span name (queued,
+  svc.preflight, execute, launch and the phases inside it);
 * launch fan-in: group sizes carried by launch spans (requests per
   batched core call);
 * metrics: every counter/gauge plus histogram p50/p95/p99 rows.

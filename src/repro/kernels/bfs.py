@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import sell_core
 from repro.kernels.backend import resolve_interpret
+from repro.obs import trace as obs_trace
 from repro.sparse.formats import SUBLANES
 
 PAD = -1
@@ -155,7 +156,9 @@ def bfs_sell(
     become RHS columns and every level is one launch set for the whole
     batch.  Returns (n_nodes,) distances for a scalar source, (n_nodes, k)
     — one column per source — for a sequence.  Columns that converge early
-    stay fixed while the rest keep expanding.
+    stay fixed while the rest keep expanding.  Each level is a
+    ``graph.level`` phase; its blocking read of whether anything changed
+    is ``graph.converge``.
     """
     sources = np.atleast_1d(np.asarray(source, np.int64))
     k = len(sources)
@@ -163,14 +166,25 @@ def bfs_sell(
     dist = dist.at[jnp.asarray(sources), jnp.arange(k)].set(0)
     max_levels = max_levels or n_nodes
     for level in range(1, max_levels + 1):
-        new = bfs_step_sell(
-            bucket_adj, bucket_nodes, dist,
-            jnp.array([level], jnp.int32), interpret=interpret,
-        )
-        if bool(jnp.all(new == dist)):
+        with obs_trace.phase("graph.level"):
+            new = bfs_step_sell(
+                bucket_adj, bucket_nodes, dist,
+                jnp.array([level], jnp.int32), interpret=interpret,
+            )
+            with obs_trace.phase("graph.converge"):
+                settled = bool(jnp.all(new == dist))
+        if settled:
             break
         dist = new
     return dist[:n_nodes, 0] if np.ndim(source) == 0 else dist[:n_nodes]
+
+
+def levels_run(dist) -> int:
+    """Level steps :func:`bfs_sell` ran to reach the host distances
+    ``dist``: one per level that reached a vertex, plus the last, which
+    found nothing new."""
+    dist = np.asarray(dist)
+    return int(np.max(dist, where=dist < INF, initial=0)) + 1
 
 
 def bfs(

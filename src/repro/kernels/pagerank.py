@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import sell_core
 from repro.kernels.backend import float_dtype, resolve_interpret
+from repro.obs import trace as obs_trace
 from repro.sparse.formats import SUBLANES
 
 PAD = -1
@@ -171,7 +172,8 @@ def pagerank_sell(
     as one launch set per power step.  A column whose ``iters`` budget is
     exhausted freezes while longer ones keep iterating.  ``out_degree`` is
     the (n_nodes,) degree vector in *original* node order; returns
-    (n_nodes,) ranks for scalar inputs, (n_nodes, k) otherwise.
+    (n_nodes,) ranks for scalar inputs, (n_nodes, k) otherwise.  Each
+    power step is a ``graph.level`` phase.
     """
     scalar = np.ndim(damping) == 0 and np.ndim(iters) == 0
     n = n_nodes
@@ -181,16 +183,17 @@ def pagerank_sell(
         deg = out_degree.astype(dtype)
         zero = jnp.zeros((1,), dtype)
         for _ in range(int(iters)):
-            contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
-            dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0))
-            consts = jnp.stack(
-                [(1.0 - damping) / n, damping, dangling / n]).astype(dtype)
-            new = pagerank_step_sell(
-                bucket_radj, bucket_nodes,
-                jnp.concatenate([contrib, zero]),   # dump slot contributes 0
-                consts, interpret=interpret,
-            )
-            rank = new[:n]
+            with obs_trace.phase("graph.level"):
+                contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
+                dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0))
+                consts = jnp.stack(
+                    [(1.0 - damping) / n, damping, dangling / n]).astype(dtype)
+                new = pagerank_step_sell(
+                    bucket_radj, bucket_nodes,
+                    jnp.concatenate([contrib, zero]),   # dump slot: 0
+                    consts, interpret=interpret,
+                )
+                rank = new[:n]
         return rank
     dampings, iters_arr = broadcast_configs(damping, iters)
     k = len(dampings)
@@ -199,16 +202,17 @@ def pagerank_sell(
     d = jnp.asarray(dampings, dtype)          # (k,)
     zero_row = jnp.zeros((1, k), dtype)
     for t in range(1, int(iters_arr.max()) + 1):
-        contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
-        dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0), axis=0)   # (k,)
-        consts = jnp.stack([(1.0 - d) / n, d, dangling / n]).astype(dtype)
-        new = pagerank_step_sell(
-            bucket_radj, bucket_nodes,
-            jnp.concatenate([contrib, zero_row]),   # dump slot contributes 0
-            consts, interpret=interpret,
-        )
-        active = jnp.asarray(t <= iters_arr)        # freeze finished columns
-        rank = jnp.where(active[None, :], new[:n], rank)
+        with obs_trace.phase("graph.level"):
+            contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
+            dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0), axis=0)
+            consts = jnp.stack([(1.0 - d) / n, d, dangling / n]).astype(dtype)
+            new = pagerank_step_sell(
+                bucket_radj, bucket_nodes,
+                jnp.concatenate([contrib, zero_row]),   # dump slot: 0
+                consts, interpret=interpret,
+            )
+            active = jnp.asarray(t <= iters_arr)    # freeze finished columns
+            rank = jnp.where(active[None, :], new[:n], rank)
     return rank
 
 

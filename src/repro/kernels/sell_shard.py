@@ -47,6 +47,7 @@ from repro.kernels import sell_core
 from repro.kernels.backend import float_dtype, resolve_interpret
 from repro.kernels.bfs import INF, _bfs_sell_step_kernel
 from repro.kernels.pagerank import _pr_sell_step_kernel, broadcast_configs
+from repro.obs import trace as obs_trace
 from repro.sparse.formats import SellSlabs, ShardedSlabs
 
 #: the canonical 1-D mesh axis name for SELL sharding
@@ -379,9 +380,12 @@ def bfs_sell_sharded(
     dist = dist.at[jnp.asarray(sources), jnp.arange(k)].set(0)
     step = _graph_step_fn(sg, mesh, _bfs_sell_step_kernel, "pmin", interpret)
     for level in range(1, (max_levels or n) + 1):
-        new = step(dist, jnp.array([level], jnp.int32), dist)
-        new = new.at[-1].set(INF)              # keep the dump slot inert
-        if bool(jnp.all(new == dist)):
+        with obs_trace.phase("graph.level"):
+            new = step(dist, jnp.array([level], jnp.int32), dist)
+            new = new.at[-1].set(INF)          # keep the dump slot inert
+            with obs_trace.phase("graph.converge"):
+                settled = bool(jnp.all(new == dist))
+        if settled:
             break
         dist = new
     return dist[:n, 0] if np.ndim(source) == 0 else dist[:n]
@@ -416,12 +420,13 @@ def pagerank_sell_sharded(
     d = jnp.asarray(dampings, dtype)
     zero_row = jnp.zeros((1, k), dtype)
     for t in range(1, int(iters_arr.max()) + 1):
-        contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
-        dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0), axis=0)
-        consts = jnp.stack([(1.0 - d) / n, d, dangling / n]).astype(dtype)
-        state = jnp.concatenate([contrib, zero_row])
-        new = step(state, consts, jnp.zeros_like(state))
-        new = new.at[-1].set(0.0)[:n]
-        active = jnp.asarray(t <= iters_arr)
-        rank = jnp.where(active[None, :], new, rank)
+        with obs_trace.phase("graph.level"):
+            contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
+            dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0), axis=0)
+            consts = jnp.stack([(1.0 - d) / n, d, dangling / n]).astype(dtype)
+            state = jnp.concatenate([contrib, zero_row])
+            new = step(state, consts, jnp.zeros_like(state))
+            new = new.at[-1].set(0.0)[:n]
+            active = jnp.asarray(t <= iters_arr)
+            rank = jnp.where(active[None, :], new, rank)
     return rank[:, 0] if scalar else rank

@@ -6,7 +6,9 @@ Four pieces, one import surface:
   (:func:`now_s` / :func:`now_us` / :class:`Stopwatch`), enforced by the
   ``timer-discipline`` lint rule;
 * :mod:`repro.obs.trace` — per-request :class:`Span` trees with fan-in
-  links, a bounded ring, JSONL and Perfetto exporters;
+  links, a bounded ring and a JSONL exporter; phase spans
+  (:func:`~repro.obs.trace.phase`) inside each request and launch, on the
+  profiler's clock when :func:`~repro.obs.trace.annotate` is on;
 * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` of counters,
   gauges and streaming histograms; :class:`CounterDict` is the
   backward-compatible view the frozen ``KernelService.stats`` contract

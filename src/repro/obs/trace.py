@@ -1,4 +1,4 @@
-"""Request tracing: spans, ring buffer, JSONL + Chrome-trace exporters.
+"""Request tracing and phase spans: spans, ring buffer, JSONL exporter.
 
 A :class:`Span` is one timed stage of one request's life (``request`` →
 ``preflight`` / ``queued`` / ``execute``) or one batched launch.  Spans
@@ -11,13 +11,23 @@ rejected and failed ones — must retire exactly one closed root span.
 
 Closed spans land in a bounded ring buffer (a long-running server must
 not grow one span per request forever); ``dropped`` counts evictions so
-an exporter can state its own truncation.  Two export formats:
+an exporter can state its own truncation.  :meth:`Tracer.export_jsonl`
+writes one span per line, the ``scripts/obs_report.py`` dashboard input.
 
-* :meth:`Tracer.export_jsonl` — one span per line, the
-  ``scripts/obs_report.py`` dashboard input;
-* :meth:`Tracer.export_chrome` — Chrome trace-event JSON, loadable in
-  Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``; each
-  request tree renders as its own track.
+:func:`phase` marks where the program is inside a request or a launch
+(``svc.prepare``, ``graph.level``, ...).  A phase does two things, each
+only when switched on:
+
+* with :func:`annotate` on, it opens a ``jax.profiler.TraceAnnotation``
+  of its name, so it lands on the host plane of a profiler trace, on the
+  same clock as the device's program executions;
+* inside :func:`children_of` (the service wraps each submit and each
+  launch in it when it has a tracer), it closes into that tracer's ring
+  as a child of the current span, so phases share the request trees' ids.
+
+With both off, :func:`phase` returns one shared null context: no clock
+read, no allocation.  The switches are process-wide, like
+:func:`repro.obs.profile.install`: the serving loop is single-threaded.
 """
 from __future__ import annotations
 
@@ -29,7 +39,8 @@ from collections import deque
 
 from repro.obs import timer
 
-__all__ = ["Span", "Tracer"]
+__all__ = ["Span", "Tracer", "annotate", "annotating", "children_of",
+           "phase"]
 
 
 @dataclasses.dataclass(slots=True)
@@ -178,42 +189,99 @@ class Tracer:
         with open(path_or_file, "w", encoding="utf-8") as fh:
             return _write(fh)
 
-    def export_chrome(self, path_or_file) -> int:
-        """Chrome trace-event JSON (Perfetto-loadable).
 
-        Closed spans become complete ("X") events with the request tree as
-        the track (tid = trace_id); fan-in links become flow events ("s"
-        arrow from each linked root into the launch span) so Perfetto
-        draws the N-requests-into-one-launch arrows.  Returns the event
-        count.
-        """
-        events = []
-        by_id = {s.span_id: s for s in self._closed}
-        for span in self._closed:
-            events.append({
-                "name": span.name,
-                "ph": "X",
-                "ts": span.start_us,
-                "dur": span.duration_us,
-                "pid": 0,
-                "tid": span.trace_id,
-                "args": {**span.attrs, "status": span.status,
-                         "span_id": span.span_id},
-            })
-            for link in span.links:
-                src = by_id.get(link)
-                if src is None:
-                    continue
-                flow = {"cat": "fanin", "id": span.span_id * 100000 + link,
-                        "pid": 0}
-                events.append({**flow, "name": "fanin", "ph": "s",
-                               "ts": src.start_us, "tid": src.trace_id})
-                events.append({**flow, "name": "fanin", "ph": "f", "bp": "e",
-                               "ts": span.start_us, "tid": span.trace_id})
-        doc = {"traceEvents": events, "displayTimeUnit": "ms"}
-        if hasattr(path_or_file, "write"):
-            json.dump(doc, path_or_file)
-        else:
-            with open(path_or_file, "w", encoding="utf-8") as fh:
-                json.dump(doc, fh)
-        return len(events)
+# -- phases ----------------------------------------------------------------
+#: ``jax.profiler.TraceAnnotation`` while :func:`annotate` is on, else None
+_ANNOTATION = None
+#: (tracer, span) that phases close into as children, set by
+#: :func:`children_of`, else None
+_PARENT: tuple[Tracer, Span] | None = None
+#: either of the two is set: the one global :func:`phase` reads
+_ON = False
+_NULL = contextlib.nullcontext()
+
+
+def _refresh() -> None:
+    global _ON
+    _ON = _ANNOTATION is not None or _PARENT is not None
+
+
+def annotate(on: bool) -> bool:
+    """Turn phase annotation on the profiler's clock on or off; returns
+    the previous setting.  JAX is imported only when it is turned on."""
+    global _ANNOTATION
+    prev = _ANNOTATION is not None
+    if on:
+        import jax.profiler
+
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    else:
+        _ANNOTATION = None
+    _refresh()
+    return prev
+
+
+def annotating() -> bool:
+    return _ANNOTATION is not None
+
+
+class _Phase:
+    """One open phase: its profiler annotation and its ring span, each
+    where switched on."""
+
+    __slots__ = ("name", "attrs", "annotation", "span", "outer")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name, self.attrs = name, attrs
+        self.annotation = self.span = self.outer = None
+
+    def __enter__(self) -> Span | None:
+        global _PARENT
+        if _ANNOTATION is not None:
+            self.annotation = _ANNOTATION(self.name)
+            self.annotation.__enter__()
+        if _PARENT is not None:
+            tracer, parent = self.outer = _PARENT
+            self.span = tracer.start(self.name, parent=parent, **self.attrs)
+            _PARENT = (tracer, self.span)
+        return self.span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        global _PARENT
+        if self.outer is not None:
+            _PARENT = self.outer
+            self.outer[0].end(self.span,
+                              status="ok" if exc_type is None else "error")
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
+def phase(name: str, **attrs):
+    """Context manager over one phase of the program called ``name``.
+    Entering it gives the phase's ring span, or None where it has none;
+    a status set on that span with :meth:`Tracer.end` inside the phase is
+    kept (``end`` keeps its first verdict)."""
+    if not _ON:
+        return _NULL
+    return _Phase(name, attrs)
+
+
+@contextlib.contextmanager
+def _parented(tracer: Tracer, span: Span):
+    global _PARENT
+    outer, _PARENT = _PARENT, (tracer, span)
+    _refresh()
+    try:
+        yield span
+    finally:
+        _PARENT = outer
+        _refresh()
+
+
+def children_of(tracer: Tracer | None, span: Span | None):
+    """Context in which phases close into ``tracer``'s ring as children of
+    ``span``; the shared null context where either is None."""
+    if tracer is None or span is None:
+        return _NULL
+    return _parented(tracer, span)

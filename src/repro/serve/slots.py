@@ -19,6 +19,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Generic, Sequence, TypeVar
 
+from repro.obs import trace as obs_trace
+
 R = TypeVar("R")
 
 
@@ -91,11 +93,13 @@ class SlotLoop(Generic[R]):
                 self.admit(i, req)
 
     def step(self) -> bool:
-        """One scheduling round: evict, admit, execute.  False = idle."""
-        self._evict_done()
-        self._fill_slots()
-        act = self.active()
-        self.observe_step(len(self.queue), len(act))
+        """One scheduling round: evict, admit, execute.  False = idle.
+        Everything before ``execute`` is the ``svc.schedule`` phase."""
+        with obs_trace.phase("svc.schedule"):
+            self._evict_done()
+            self._fill_slots()
+            act = self.active()
+            self.observe_step(len(self.queue), len(act))
         if not act:
             return False
         self.execute(act)

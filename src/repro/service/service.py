@@ -29,11 +29,16 @@ needs.  Per-request submit/finish timestamps feed
 
 Observability (:mod:`repro.obs`) threads through every stage: ``stats``
 is a live view over the service's :class:`~repro.obs.MetricsRegistry`
-counters, per-op latency / group-size / launch-wall histograms and
+counters, per-class latency / group-size / launch-wall histograms and
 queue-depth / in-flight gauges accumulate alongside, and an optional
 :class:`~repro.obs.Tracer` records one span tree per request — including
 rejected and failed ones — with batched launch spans fanning in their
-group members via links.
+group members via links.  Phase spans (:func:`repro.obs.trace.phase`)
+mark where each step spends its host time: ``svc.schedule`` (the slot
+loop), ``svc.preflight`` (each submit), and per launch ``svc.prepare``
+(validate, cast, stack, pad, device put), ``svc.launch`` (the kernel
+call; the graph level loop inside it), ``svc.fetch`` (the device wait
+and copy back) and ``svc.split`` (results to the group's requests).
 """
 from __future__ import annotations
 
@@ -53,6 +58,7 @@ from repro.obs import (
     Tracer,
     timer,
 )
+from repro.obs import trace as obs_trace
 from repro.analysis.preflight import (
     plan_bfs_sell,
     plan_fft_stockham,
@@ -98,6 +104,7 @@ STATS_KEYS = (
     "streamed_launches",    # launches on the out-of-VMEM streaming path
     "sharded_launches",     # launches on the multi-device sharded path
     "moe_dispatch_launches",  # batched MoE combine launches (LM serving)
+    "graph_steps",          # BFS levels / PageRank power steps launched
 )
 
 
@@ -132,6 +139,36 @@ def _pow2_pad(items: list) -> list:
     from repro.kernels.sell_core import pow2_ceil
 
     return items + [items[-1]] * (pow2_ceil(len(items)) - len(items))
+
+
+def _block_diagonal(payloads: list, dtype: str) -> tuple:
+    """Stack MoE combine requests block-diagonally: request i's tokens
+    occupy rows [row_off_i, row_off_i + n_tok_i), its slots the matching
+    column band — one operand, one launch.  Returns the CSR operand, the
+    stacked expert outputs and each request's row span."""
+    from repro.sparse.formats import CSRMatrix
+
+    indptrs, indices_all, data_all, xs, spans = [np.zeros(1, np.int64)], \
+        [], [], [], []
+    row_off = col_off = nnz_off = 0
+    for indptr, indices, data, x in payloads:
+        spans.append((row_off, row_off + indptr.shape[0] - 1))
+        indptrs.append(indptr[1:] + nnz_off)
+        indices_all.append(indices + col_off)
+        data_all.append(data)
+        xs.append(x)
+        row_off += indptr.shape[0] - 1
+        col_off += x.shape[0]
+        nnz_off += int(indptr[-1])
+    csr = CSRMatrix(
+        indptr=np.concatenate(indptrs),
+        indices=np.concatenate(indices_all).astype(np.int32)
+        if indices_all else np.zeros(0, np.int32),
+        data=np.concatenate(data_all)
+        if data_all else np.zeros(0, np.dtype(dtype)),
+        n_cols=col_off,
+    )
+    return csr, np.vstack(xs), spans
 
 
 @dataclasses.dataclass
@@ -264,13 +301,13 @@ class KernelService(SlotLoop[KernelRequest]):
                 raise TypeError(
                     f"spec must be an ExecSpec, got {type(spec).__name__}")
             record = self.registry.get(operand)  # fail fast: unknown operand
-            pre = self._t_start("preflight", parent=root)
-            try:
-                self._preflight(op, record)      # ... infeasible launches
-            except LaunchPlanError:
-                self._t_end(pre, status="rejected")
-                raise
-            self._t_end(pre)
+            with obs_trace.children_of(self.tracer, root), \
+                    obs_trace.phase("svc.preflight") as pre:
+                try:
+                    self._preflight(op, record)  # ... infeasible launches
+                except LaunchPlanError:
+                    self._t_end(pre, status="rejected")
+                    raise
             if self.max_queue is not None and \
                     len(self.queue) >= self.max_queue:
                 self.stats["rejected"] += 1
@@ -470,9 +507,6 @@ class KernelService(SlotLoop[KernelRequest]):
         if req.done_t:
             lat_us = (req.done_t - req.submit_t) * 1e6
             self._latencies_us.append(lat_us)
-            self.metrics.histogram(
-                f"latency_us_{req.op}",
-                f"submit->result latency of {req.op} requests").observe(lat_us)
             cls = OP_CLASS.get(req.op, "kernel")
             self.metrics.histogram(
                 f"latency_us_class_{cls}",
@@ -506,7 +540,8 @@ class KernelService(SlotLoop[KernelRequest]):
                 "launch", op=op, operand=operand, group_size=len(reqs),
                 links=[r.span for r in reqs if r.span is not None])
             try:
-                self._run_group(op, self.registry.get(operand), reqs)
+                with obs_trace.children_of(self.tracer, launch):
+                    self._run_group(op, self.registry.get(operand), reqs)
             except Exception as exc:  # noqa: BLE001 - errors belong to requests
                 for req in reqs:
                     if not req.done:
@@ -543,6 +578,18 @@ class KernelService(SlotLoop[KernelRequest]):
             self.profiler.record(
                 op=op, operand=operand.name, wall_us=wall_us,
                 plan=operand.plans.get(op))
+
+    def _fetched(self, operand: RegisteredOperand, op: str, out,
+                 sw: Stopwatch):
+        """Bring a launch's result to the host (``svc.fetch``: the wait for
+        the device and the copy back), stop the launch's stopwatch and
+        count the launch.  Returns numpy arrays (a tuple stays a tuple)."""
+        with obs_trace.phase("svc.fetch"):
+            out = tuple(map(np.asarray, out)) if isinstance(out, tuple) \
+                else np.asarray(out)
+        sw.stop()
+        self._count_launch(operand, op=op, wall_us=sw.elapsed_us)
+        return out
 
     @staticmethod
     def _validated(reqs: list[KernelRequest], check) -> tuple[list, list]:
@@ -583,42 +630,43 @@ class KernelService(SlotLoop[KernelRequest]):
                 raise ValueError(f"x must have shape ({n_cols},), got {x.shape}")
             return x
 
-        good, xs = self._validated(reqs, check)
-        if not good:
-            return
-        # pow2-pad the RHS stack BEFORE the jitted core: jax.jit keys on
-        # the pre-pad (n_cols, k) shape, so without this every distinct
-        # group size would trace its own program (see _pow2_pad)
-        x_stack = jnp.asarray(np.stack(_pow2_pad(xs), axis=1))
+        with obs_trace.phase("svc.prepare"):
+            good, xs = self._validated(reqs, check)
+            if not good:
+                return
+            # pow2-pad the RHS stack BEFORE the jitted core: jax.jit keys
+            # on the pre-pad (n_cols, k) shape, so without this every
+            # distinct group size would trace its own program (_pow2_pad)
+            x_stack = jnp.asarray(np.stack(_pow2_pad(xs), axis=1))
         sw = Stopwatch().start()
-        if operand.mode == "sharded":
-            from repro.kernels import sell_shard
+        with obs_trace.phase("svc.launch"):
+            if operand.mode == "sharded":
+                from repro.kernels import sell_shard
 
-            y = sell_shard.spmm_sell_sharded(
-                operand.sharded, x_stack, mesh=self.registry.mesh,
-                w_block=tuned.w_block, k_block=tuned.k_block,
-                interpret=self.interpret,
-            )
-            self.stats["sharded_launches"] += 1
-        elif operand.mode == "stream":
-            y = sell_core.spmm_sell_stream(
-                arrs["cols"], arrs["vals"], arrs["rows"], x_stack,
-                n_rows=operand.n, w_block=tuned.w_block,
-                k_block=tuned.k_block, col_tile=tuned.col_tile,
-                row_tile=tuned.row_tile, interpret=self.interpret,
-            )
-            self.stats["streamed_launches"] += 1
-        else:
-            y = sell_core.spmm_sell(
-                arrs["cols"], arrs["vals"], arrs["rows"], x_stack,
-                n_rows=operand.n, w_block=tuned.w_block,
-                k_block=tuned.k_block, interpret=self.interpret,
-            )
-        y = np.asarray(y)          # forces the async dispatch: real wall time
-        sw.stop()
-        self._count_launch(operand, op="spmv", wall_us=sw.elapsed_us)
-        for i, req in enumerate(good):
-            req.result = y[:, i]
+                y = sell_shard.spmm_sell_sharded(
+                    operand.sharded, x_stack, mesh=self.registry.mesh,
+                    w_block=tuned.w_block, k_block=tuned.k_block,
+                    interpret=self.interpret,
+                )
+                self.stats["sharded_launches"] += 1
+            elif operand.mode == "stream":
+                y = sell_core.spmm_sell_stream(
+                    arrs["cols"], arrs["vals"], arrs["rows"], x_stack,
+                    n_rows=operand.n, w_block=tuned.w_block,
+                    k_block=tuned.k_block, col_tile=tuned.col_tile,
+                    row_tile=tuned.row_tile, interpret=self.interpret,
+                )
+                self.stats["streamed_launches"] += 1
+            else:
+                y = sell_core.spmm_sell(
+                    arrs["cols"], arrs["vals"], arrs["rows"], x_stack,
+                    n_rows=operand.n, w_block=tuned.w_block,
+                    k_block=tuned.k_block, interpret=self.interpret,
+                )
+        y = self._fetched(operand, "spmv", y, sw)
+        with obs_trace.phase("svc.split"):
+            for i, req in enumerate(good):
+                req.result = y[:, i]
 
     def _run_bfs(self, operand, reqs):
         """The whole group is one batched drive: sources become frontier
@@ -635,36 +683,38 @@ class KernelService(SlotLoop[KernelRequest]):
                 raise ValueError(f"source {source} out of range [0, {operand.n})")
             return source
 
-        good, sources = self._validated(reqs, check)
-        if not good:
-            return
-        # a singleton group keeps the 1-D fast path (no RHS axis to drag
-        # through every gather); larger groups batch sources as columns,
-        # padded to a power of two (repeat the last source) so 1..n_slots
-        # group sizes share log2 compiled programs instead of one each
-        batch = sources[0] if len(good) == 1 else _pow2_pad(sources)
+        with obs_trace.phase("svc.prepare"):
+            good, sources = self._validated(reqs, check)
+            if not good:
+                return
+            # a singleton group keeps the 1-D fast path (no RHS axis to
+            # drag through every gather); larger groups batch sources as
+            # columns, padded to a power of two (repeat the last source)
+            # so 1..n_slots group sizes share log2 compiled programs
+            batch = sources[0] if len(good) == 1 else _pow2_pad(sources)
         sw = Stopwatch().start()
-        if operand.sharded is not None:
-            from repro.kernels import sell_shard
+        with obs_trace.phase("svc.launch"):
+            if operand.sharded is not None:
+                from repro.kernels import sell_shard
 
-            dist = sell_shard.bfs_sell_sharded(
-                operand.sharded, batch, mesh=self.registry.mesh,
-                interpret=self.interpret,
-            )
-            self.stats["sharded_launches"] += 1
-        else:
-            dist = bfs_k.bfs_sell(
-                arrs["adj"], arrs["nodes"], operand.n, batch,
-                interpret=self.interpret,
-            )
-        dist = np.asarray(dist)
-        sw.stop()
-        self._count_launch(operand, op="bfs", wall_us=sw.elapsed_us)
-        if len(good) == 1:
-            good[0].result = dist
-        else:
-            for i, req in enumerate(good):
-                req.result = dist[:, i]
+                dist = sell_shard.bfs_sell_sharded(
+                    operand.sharded, batch, mesh=self.registry.mesh,
+                    interpret=self.interpret,
+                )
+                self.stats["sharded_launches"] += 1
+            else:
+                dist = bfs_k.bfs_sell(
+                    arrs["adj"], arrs["nodes"], operand.n, batch,
+                    interpret=self.interpret,
+                )
+        dist = self._fetched(operand, "bfs", dist, sw)
+        self.stats["graph_steps"] += bfs_k.levels_run(dist)
+        with obs_trace.phase("svc.split"):
+            if len(good) == 1:
+                good[0].result = dist
+            else:
+                for i, req in enumerate(good):
+                    req.result = dist[:, i]
 
     def _run_pagerank(self, operand, reqs):
         """The whole group is one batched drive: (damping, iters) configs
@@ -679,37 +729,41 @@ class KernelService(SlotLoop[KernelRequest]):
             return (float(req.params.get("damping", 0.85)),
                     int(req.params.get("iters", 20)))
 
-        good, configs = self._validated(reqs, check)
-        if not good:
-            return
-        if len(good) == 1:                     # 1-D fast path (see _run_bfs)
-            damping, iters = configs[0]
-        else:                                  # pow2-padded columns, ditto
-            configs = _pow2_pad(configs)
-            damping = [d for d, _ in configs]
-            iters = [i for _, i in configs]
+        with obs_trace.phase("svc.prepare"):
+            good, configs = self._validated(reqs, check)
+            if not good:
+                return
+            if len(good) == 1:                 # 1-D fast path (_run_bfs)
+                damping, iters = configs[0]
+            else:                              # pow2-padded columns, ditto
+                configs = _pow2_pad(configs)
+                damping = [d for d, _ in configs]
+                iters = [i for _, i in configs]
         sw = Stopwatch().start()
-        if operand.sharded is not None:
-            from repro.kernels import sell_shard
+        with obs_trace.phase("svc.launch"):
+            if operand.sharded is not None:
+                from repro.kernels import sell_shard
 
-            rank = sell_shard.pagerank_sell_sharded(
-                operand.sharded, arrs["out_degree"], mesh=self.registry.mesh,
-                damping=damping, iters=iters, interpret=self.interpret,
-            )
-            self.stats["sharded_launches"] += 1
-        else:
-            rank = pr_k.pagerank_sell(
-                arrs["adj"], arrs["nodes"], arrs["out_degree"], operand.n,
-                damping=damping, iters=iters, interpret=self.interpret,
-            )
-        rank = np.asarray(rank)
-        sw.stop()
-        self._count_launch(operand, op="pagerank", wall_us=sw.elapsed_us)
-        if len(good) == 1:
-            good[0].result = rank
-        else:
-            for i, req in enumerate(good):
-                req.result = rank[:, i]
+                rank = sell_shard.pagerank_sell_sharded(
+                    operand.sharded, arrs["out_degree"],
+                    mesh=self.registry.mesh, damping=damping, iters=iters,
+                    interpret=self.interpret,
+                )
+                self.stats["sharded_launches"] += 1
+            else:
+                rank = pr_k.pagerank_sell(
+                    arrs["adj"], arrs["nodes"], arrs["out_degree"],
+                    operand.n, damping=damping, iters=iters,
+                    interpret=self.interpret,
+                )
+        rank = self._fetched(operand, "pagerank", rank, sw)
+        self.stats["graph_steps"] += int(np.max(iters))
+        with obs_trace.phase("svc.split"):
+            if len(good) == 1:
+                good[0].result = rank
+            else:
+                for i, req in enumerate(good):
+                    req.result = rank[:, i]
 
     def _run_fft(self, operand, reqs):
         """True micro-batch: stack every request's signal rows into one
@@ -739,25 +793,26 @@ class KernelService(SlotLoop[KernelRequest]):
                                  f"registered fft length {n}")
             return sig
 
-        good, sigs = self._validated(reqs, check)
-        if not good:
-            return
-        rows, spans = [], []
-        for sig in sigs:
-            spans.append((len(rows), len(rows) + sig.shape[0]))
-            rows.extend(sig)
-        batch = jnp.asarray(np.stack(rows))
+        with obs_trace.phase("svc.prepare"):
+            good, sigs = self._validated(reqs, check)
+            if not good:
+                return
+            rows, spans = [], []
+            for sig in sigs:
+                spans.append((len(rows), len(rows) + sig.shape[0]))
+                rows.extend(sig)
+            batch = jnp.asarray(np.stack(rows))
         sw = Stopwatch().start()
-        re, im = fft_k.fft_stockham(
-            batch, jnp.zeros_like(batch),
-            operand.device_arrays["wre"], operand.device_arrays["wim"],
-            b_block=min(8, batch.shape[0]), interpret=self.interpret,
-        )
-        re, im = np.asarray(re), np.asarray(im)
-        sw.stop()
-        self._count_launch(operand, op="fft", wall_us=sw.elapsed_us)
-        for req, (lo, hi) in zip(good, spans):
-            req.result = (re[lo:hi], im[lo:hi])
+        with obs_trace.phase("svc.launch"):
+            out = fft_k.fft_stockham(
+                batch, jnp.zeros_like(batch),
+                operand.device_arrays["wre"], operand.device_arrays["wim"],
+                b_block=min(8, batch.shape[0]), interpret=self.interpret,
+            )
+        re, im = self._fetched(operand, "fft", out, sw)
+        with obs_trace.phase("svc.split"):
+            for req, (lo, hi) in zip(good, spans):
+                req.result = (re[lo:hi], im[lo:hi])
 
     def _run_moe_dispatch(self, operand, reqs):
         """The whole group is ONE batched combine SpMM: each request's
@@ -767,7 +822,6 @@ class KernelService(SlotLoop[KernelRequest]):
         This is the fusion point where ServeEngine's MoE traffic coalesces
         with kernel traffic on the shared slot loop."""
         from repro.kernels import ops
-        from repro.sparse.formats import CSRMatrix
 
         if operand.kind != "moe":
             raise TypeError(f"operand {operand.name!r} is not a moe envelope")
@@ -804,40 +858,19 @@ class KernelService(SlotLoop[KernelRequest]):
                 raise ValueError("routing column index out of range")
             return (indptr, indices, data, x)
 
-        good, payloads = self._validated(reqs, check)
-        if not good:
-            return
-        # block-diagonal stack: request i's tokens occupy rows
-        # [row_off_i, row_off_i + n_tok_i), its slots the matching column
-        # band — one operand, one launch, per-request row spans
-        indptrs, indices_all, data_all, xs, spans = [np.zeros(1, np.int64)], \
-            [], [], [], []
-        row_off = col_off = nnz_off = 0
-        for indptr, indices, data, x in payloads:
-            spans.append((row_off, row_off + indptr.shape[0] - 1))
-            indptrs.append(indptr[1:] + nnz_off)
-            indices_all.append(indices + col_off)
-            data_all.append(data)
-            xs.append(x)
-            row_off += indptr.shape[0] - 1
-            col_off += x.shape[0]
-            nnz_off += int(indptr[-1])
-        csr = CSRMatrix(
-            indptr=np.concatenate(indptrs),
-            indices=np.concatenate(indices_all).astype(np.int32)
-            if indices_all else np.zeros(0, np.int32),
-            data=np.concatenate(data_all)
-            if data_all else np.zeros(0, np.dtype(m["dtype"])),
-            n_cols=col_off,
-        )
-        x_stack = np.vstack(xs)
+        with obs_trace.phase("svc.prepare"):
+            good, payloads = self._validated(reqs, check)
+            if not good:
+                return
+            csr, x_stack, spans = _block_diagonal(payloads, m["dtype"])
         spec = ExecSpec(dispatch="sell", vl=m["c"],
                         k_block=_moe_k_block(d),
                         interpret=self.interpret)
         sw = Stopwatch().start()
-        y = np.asarray(ops.moe_dispatch(csr, x_stack, spec=spec, top_k=top_k))
-        sw.stop()
+        with obs_trace.phase("svc.launch"):
+            y = ops.moe_dispatch(csr, x_stack, spec=spec, top_k=top_k)
         self.stats["moe_dispatch_launches"] += 1
-        self._count_launch(operand, op="moe_dispatch", wall_us=sw.elapsed_us)
-        for req, (lo, hi) in zip(good, spans):
-            req.result = y[lo:hi]
+        y = self._fetched(operand, "moe_dispatch", y, sw)
+        with obs_trace.phase("svc.split"):
+            for req, (lo, hi) in zip(good, spans):
+                req.result = y[lo:hi]

@@ -37,6 +37,7 @@ from repro.obs import (
     now_us,
     profiled,
 )
+from repro.obs import trace as obs_trace
 from repro.service import KernelRegistry, KernelService, QueueFull
 from repro.service.service import STATS_KEYS
 from repro.sparse import formats as F
@@ -45,6 +46,23 @@ RNG = np.random.default_rng(11)
 
 
 MAT = F.random_csr(64, 64, 4.0, seed=5)
+GRAPH = G.random_graph(n_nodes=32, avg_degree=3, seed=0)
+
+
+def _bfs_levels(graph, source=0):
+    """Level steps of a level-synchronous BFS from ``source``: one per
+    level that reaches a vertex, plus the last, which reaches none."""
+    adj = [{int(v) for v in row if v != G.PAD} for row in graph.adj]
+    seen, frontier, levels = {source}, {source}, 1
+    while True:
+        frontier = {v for u in frontier for v in adj[u]} - seen
+        if not frontier:
+            return levels
+        seen |= frontier
+        levels += 1
+
+
+GRAPH_LEVELS = _bfs_levels(GRAPH)
 
 
 def make_service(**kw):
@@ -215,15 +233,11 @@ def test_exporters_roundtrip_and_flag_open_spans(tmp_path):
                                          "execute"}
     buf = io.StringIO()
     assert t.export_jsonl(buf, include_open=False) == 3
+    # the fan-in link survives the round trip
+    (launch,) = [d for d in docs if d["name"] == "launch"]
+    assert launch["links"] == [root.span_id]
 
-    # chrome export: 3 closed "X" events + one fan-in flow pair (s, f)
     t.end(orphan)
-    chrome = tmp_path / "trace_chrome.json"
-    assert t.export_chrome(str(chrome)) == 4 + 2
-    events = json.loads(chrome.read_text())["traceEvents"]
-    assert sum(1 for e in events if e["ph"] == "X") == 4
-    assert {e["ph"] for e in events if e["name"] == "fanin"} == {"s", "f"}
-
     t.reset()
     assert t.open_count == 0 and not t.spans() and t.dropped == 0
 
@@ -284,7 +298,7 @@ def test_served_request_closes_full_span_tree():
     (root,) = t.closed_roots("request")
     assert root.status == "ok" and root.attrs["rid"] == rid
     stages = {s.name for s in t.children(root)}
-    assert stages == {"preflight", "queued", "execute"}
+    assert stages == {"svc.preflight", "queued", "execute"}
     (launch,) = [s for s in t.spans() if s.name == "launch"]
     assert launch.parent_id is None            # fan-in root, not a child
     assert launch.links == (root.span_id,)
@@ -293,7 +307,8 @@ def test_served_request_closes_full_span_tree():
     assert svc.metrics.get("queue_depth").value == 0
     assert svc.metrics.get("in_flight").value == 0
     assert svc.metrics.get("planned_vmem_bytes").value > 0
-    assert svc.metrics.get("latency_us_spmv").snapshot()["count"] == 1
+    assert svc.metrics.get("latency_us_class_kernel").snapshot()["count"] == 1
+    assert "latency_us_spmv" not in svc.metrics    # per class, not per op
 
 
 def test_queue_full_rejection_closes_root_as_rejected():
@@ -325,7 +340,7 @@ def test_preflight_rejection_closes_root_and_child():
     (root,) = t.closed_roots("request")
     assert root.status == "rejected" and root.attrs["reason"] == "preflight"
     (pre,) = t.children(root)
-    assert pre.name == "preflight" and pre.status == "rejected"
+    assert pre.name == "svc.preflight" and pre.status == "rejected"
 
 
 def test_failed_groupmate_closes_as_error_others_ok():
@@ -382,13 +397,129 @@ def test_mixed_load_leaves_zero_orphans():
     assert svc.metrics.get("launch_wall_us_spmv").snapshot()["count"] > 0
 
 
-def test_service_without_tracer_pays_nothing_and_still_counts():
+def test_service_without_tracer_pays_nothing_and_still_counts(monkeypatch):
+    def opened(*_a, **_k):
+        raise AssertionError("a phase was opened with tracing off")
+
+    monkeypatch.setattr(obs_trace, "_Phase", opened)
     svc = make_service(tracer=None)
+    svc.registry.register_graph("g", GRAPH)
     svc.submit("spmv", "m", RNG.standard_normal(64))
+    svc.submit("bfs", "g", source=0)
+    svc.submit("pagerank", "g", damping=0.85, iters=3)
     svc.drain()
     assert svc.tracer is None
-    assert svc.stats["served"] == 1            # CounterDict path unaffected
+    assert not obs_trace.annotating() and obs_trace._PARENT is None
+    assert svc.stats["served"] == 3            # CounterDict path unaffected
+    assert svc.stats["launches"] == 3 and svc.stats["steps"] == 1
     assert dict(svc.stats) == {k: svc.stats[k] for k in STATS_KEYS}
+    assert svc.stats["graph_steps"] == GRAPH_LEVELS + 3
+
+
+# ---------------------------------------------------------------------------
+# Phase spans
+# ---------------------------------------------------------------------------
+
+
+def test_phase_off_is_one_shared_null_context(monkeypatch):
+    import tracemalloc
+
+    def clock():
+        raise AssertionError("a phase read the clock with tracing off")
+
+    monkeypatch.setattr(obs_trace.timer, "now_us", clock)
+    assert not obs_trace.annotating()
+    a, b = obs_trace.phase("svc.prepare"), obs_trace.phase("graph.level")
+    assert a is b
+    assert obs_trace.children_of(None, None) is a
+    assert obs_trace.children_of(Tracer(), None) is a
+    with a as span:
+        assert span is None
+    for _ in range(10):                        # warm the call path
+        with obs_trace.phase("graph.level"):
+            pass
+    tracemalloc.start()
+    before = tracemalloc.take_snapshot()
+    for _ in range(1000):
+        with obs_trace.phase("graph.level"):
+            pass
+    grown = tracemalloc.take_snapshot().compare_to(before, "lineno")
+    tracemalloc.stop()
+    assert sum(d.count_diff for d in grown
+               if "obs/trace.py" in str(d.traceback)) <= 0
+
+
+def test_phases_nest_under_their_parent_and_restore_it():
+    t = Tracer()
+    root = t.start("request")
+    with obs_trace.children_of(t, root):
+        with obs_trace.phase("svc.launch", op="bfs") as outer:
+            with obs_trace.phase("graph.level") as inner:
+                assert inner.parent_id == outer.span_id
+        with pytest.raises(RuntimeError):
+            with obs_trace.phase("svc.fetch"):
+                raise RuntimeError("device lost")
+    t.end(root)
+    assert obs_trace.phase("x") is obs_trace.phase("y")    # switched off
+    by_name = {s.name: s for s in t.spans()}
+    assert by_name["svc.launch"].parent_id == root.span_id
+    assert by_name["svc.launch"].attrs == {"op": "bfs"}
+    assert by_name["svc.fetch"].parent_id == root.span_id
+    assert by_name["svc.fetch"].status == "error"
+    assert t.open_count == 0 and t.closed_roots() == [root]
+
+
+def test_annotate_opens_a_profiler_annotation_per_phase(monkeypatch):
+    import jax.profiler
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("close", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    assert obs_trace.annotate(True) is False
+    try:
+        assert obs_trace.annotating()
+        with obs_trace.phase("graph.level") as span:
+            with obs_trace.phase("graph.converge"):
+                pass
+        assert span is None                    # no tracer: no ring span
+    finally:
+        assert obs_trace.annotate(False) is True
+    assert seen == [("open", "graph.level"), ("open", "graph.converge"),
+                    ("close", "graph.converge"), ("close", "graph.level")]
+    assert not obs_trace.annotating()
+
+
+def test_launch_phases_close_into_the_ring_under_the_launch():
+    svc = make_service()
+    svc.registry.register_graph("g", GRAPH)
+    svc.submit("spmv", "m", RNG.standard_normal(64))
+    svc.submit("bfs", "g", source=0)
+    svc.drain()
+    t = svc.tracer
+    assert t.open_count == 0
+    assert len(t.closed_roots("request")) == 2
+    for launch in (s for s in t.spans() if s.name == "launch"):
+        kids = [s.name for s in t.children(launch)]
+        assert kids == ["svc.prepare", "svc.launch", "svc.fetch",
+                        "svc.split"], kids
+    (bfs_launch,) = [s for s in t.spans() if s.name == "svc.launch"
+                     and t.children(s)]
+    levels = [s for s in t.children(bfs_launch) if s.name == "graph.level"]
+    assert len(levels) == GRAPH_LEVELS == svc.stats["graph_steps"]
+    for level in levels:
+        assert [c.name for c in t.children(level)] == ["graph.converge"]
+    # the scheduling phase has no parent span: profiler annotation only
+    assert not [s for s in t.spans() if s.name == "svc.schedule"]
 
 
 # ---------------------------------------------------------------------------
