@@ -163,7 +163,8 @@ def _register_graph(registry, sizes: Sizes, seed: int = 0):
             continue
         print(f"[graph] R-MAT scale {scale}: {g.n_nodes} nodes, "
               f"{g.n_edges} edges, C={rec.tuned.c}, "
-              f"buckets={list(rec.slab_meta.widths)}", flush=True)
+              f"buckets={list(rec.slab_meta.widths)}, split={rec.split}",
+              flush=True)
         return g, rec
     raise RuntimeError("no R-MAT scale fits the launch plans")
 

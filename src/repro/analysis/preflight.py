@@ -466,7 +466,8 @@ def _plan_node_step(
     """Shared plan for the ``bucketed_node_step`` drivers (BFS, PageRank):
     per bucket one (1, W, C) adjacency tile, the whole (n + 1, k) state as
     a lane table, and a (1, k, C) output tile — each double-buffered by
-    the pipeline.  The step's scalars sit in SMEM."""
+    the pipeline.  BFS's level sits in SMEM; PageRank's damping is
+    applied after the scatter."""
     violations: list[str] = []
     if k < 1:
         violations.append(f"state stack must have k >= 1 columns, got {k}")

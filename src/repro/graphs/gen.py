@@ -64,6 +64,12 @@ class SellGraphSlabs:
     node-major, matching the (vl, width) orientation of the BFS/PageRank
     kernels — and ``bucket_nodes[b]`` is (n_slices_b, C) mapping each lane
     to its original node id (``n_nodes`` = padding/dump slot).
+
+    A node whose degree exceeds :data:`repro.sparse.formats.SPLIT_WIDTH`
+    is packed as several virtual rows of near-equal length
+    (:func:`split_rows`), so no slice is padded to a hub's degree: its
+    lanes all map to that node, and the node step combines their partial
+    results.
     """
 
     bucket_adj: tuple[np.ndarray, ...]    # each (n_slices_b, C, W_b) int32
@@ -106,10 +112,47 @@ def _edges(g: EllpackGraph, reverse: bool = False
     return dst[by_dst], src[by_dst]
 
 
+def split_rows(deg: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(owner, length) of each virtual row: a node of degree d above
+    :data:`repro.sparse.formats.SPLIT_WIDTH` becomes ``ceil(d /
+    SPLIT_WIDTH)`` consecutive rows of near-equal length, every other node
+    one row of its own degree, all in node order."""
+    from repro.sparse.formats import SPLIT_WIDTH
+
+    deg = np.asarray(deg, np.int64)
+    n = len(deg)
+    if not n or deg.max() <= SPLIT_WIDTH:
+        return np.arange(n, dtype=np.int64), deg
+    pieces = np.maximum(-(-deg // SPLIT_WIDTH), 1)
+    owner = np.repeat(np.arange(n, dtype=np.int64), pieces)
+    piece = np.arange(len(owner), dtype=np.int64) - (
+        np.cumsum(pieces) - pieces)[owner]
+    d, p = deg[owner], pieces[owner]
+    return owner, d // p + (piece < d % p)
+
+
+def split_stats(slabs) -> dict:
+    """What the split engaged in :class:`SellGraphSlabs` or
+    :class:`ShardedGraphSlabs`: the split width (None when no node was
+    split), the nodes cut into more than one virtual row, the virtual rows
+    and the padded slots a node step sweeps."""
+    from repro.sparse.formats import SPLIT_WIDTH
+
+    n = slabs.n_nodes
+    owners = np.concatenate([m.reshape(-1) for m in slabs.bucket_nodes])
+    rows = np.bincount(owners, minlength=n + 1)[:n]
+    split_nodes = int(np.count_nonzero(rows > 1))
+    return {"split_width": SPLIT_WIDTH if split_nodes else None,
+            "split_nodes": split_nodes,
+            "virtual_rows": int(rows.sum()),
+            "padded_slots": int(sum(a.size for a in slabs.bucket_adj))}
+
+
 def _pack_edges(nodes: np.ndarray, nbrs: np.ndarray, n: int, c: int,
                 sigma: int) -> SellGraphSlabs:
     """SELL slabs of an adjacency given as (node, neighbour) pairs grouped
-    by ascending node: each node's neighbours fill its lane left to right.
+    by ascending node: each virtual row's (:func:`split_rows`) neighbours
+    fill its lane left to right.
 
     Only the padded slots are allocated — never the n x (max degree)
     degree-padded matrix, which for a skewed graph whose hub has tens of
@@ -118,18 +161,23 @@ def _pack_edges(nodes: np.ndarray, nbrs: np.ndarray, n: int, c: int,
     from repro.sparse.formats import next_pow2, sigma_sort_order, slice_widths
 
     deg = np.bincount(nodes, minlength=n).astype(np.int64)
-    starts = np.zeros(n + 1, np.int64)
-    np.cumsum(deg, out=starts[1:])
-    within = np.arange(len(nodes), dtype=np.int64) - starts[nodes]
-    order = sigma_sort_order(deg, sigma)
-    bwidths = next_pow2(slice_widths(deg, order, c))
+    owner, lengths = split_rows(deg)
+    n_rows = len(owner)
+    # a node's virtual rows are consecutive, so the edge list grouped by
+    # node is already grouped by virtual row
+    vrow = np.repeat(np.arange(n_rows, dtype=np.int64), lengths)
+    starts = np.zeros(n_rows + 1, np.int64)
+    np.cumsum(lengths, out=starts[1:])
+    within = np.arange(len(nodes), dtype=np.int64) - starts[vrow]
+    order = sigma_sort_order(lengths, sigma)
+    bwidths = next_pow2(slice_widths(lengths, order, c))
     n_slices = len(bwidths)
     nodes_padded = np.full(n_slices * c, n, np.int64)
-    nodes_padded[:n] = order
+    nodes_padded[:n_rows] = owner[order]
     nodes_by_slice = nodes_padded.reshape(n_slices, c).astype(np.int32)
-    pos = np.empty(n, np.int64)
-    pos[order] = np.arange(n)
-    e_slice, e_lane = pos[nodes] // c, pos[nodes] % c
+    pos = np.empty(n_rows, np.int64)
+    pos[order] = np.arange(n_rows)
+    e_slice, e_lane = pos[vrow] // c, pos[vrow] % c
     bucket_adj, bucket_nodes = [], []
     for w in np.unique(bwidths):
         ids = np.nonzero(bwidths == w)[0]
@@ -157,7 +205,8 @@ def graph_to_sell_slabs(
     ``reverse=True`` packs the in-neighbours — the slabs of
     ``graph_to_sell_slabs(g.transpose(), ...)``, which the pull-style BFS
     and PageRank kernels read — straight from the edge list, without the
-    degree-padded reverse graph.
+    degree-padded reverse graph.  Rows longer than the split width are
+    packed as virtual rows (:func:`split_rows`).
     """
     return _pack_edges(*_edges(g, reverse), g.n_nodes, c,
                        int(sigma or 8 * c))
@@ -183,7 +232,7 @@ class ShardedGraphSlabs:
     owned-node ids (padding lanes map to ``n_nodes``, the shared dump slot)
     — each shard scatters only its own nodes, and the cross-device combine
     (BFS ``pmin`` frontier union, PageRank ``psum`` rank exchange) merges
-    the disjoint updates.
+    the disjoint updates.  A split node's virtual rows stay in its shard.
     """
 
     bucket_adj: tuple[np.ndarray, ...]    # each (n_shards, S_b, C, W_b) int32

@@ -92,7 +92,9 @@ def _bfs_sell_step_kernel(adj_ref, nodes_ref, dist_ref, level_ref, out_ref):
 
     One output row per stacked source column kk of the (k, R, lanes)
     distance table: a node still at INF joins the frontier at ``level``
-    when any of its in-neighbors sits at ``level - 1``.
+    when any of its in-neighbors sits at ``level - 1``.  Each virtual row
+    of a split node yields either its owner's distance or ``level``, so
+    the min-combining scatter of :func:`bfs_step_sell` is exact.
     """
     level = level_ref[0]
     width, c = adj_ref.shape[1:]
@@ -130,12 +132,13 @@ def bfs_step_sell(
 
     ``dist`` is (n + 1,) for a single source or (n + 1, k) for k stacked
     sources (the dump slot stays INF); returns the updated copy with the
-    same shape.  One launch set advances every column.
+    same shape.  One launch set advances every column; the virtual rows
+    of a split node merge by minimum.
     """
     cols = dist if dist.ndim == 2 else dist[:, None]
     out = sell_core.bucketed_node_step(
         _bfs_sell_step_kernel, bucket_adj, bucket_nodes,
-        cols, level, cols, interpret=interpret,
+        cols, level, cols, combine="min", interpret=interpret,
     )
     out = out.at[-1].set(INF)                 # keep the dump slot inert
     return out if dist.ndim == 2 else out[:, 0]

@@ -626,7 +626,8 @@ def bfs(
 
     ``spec.layout = "sell"`` runs the width-bucketed kernel over
     in-degree-sorted adjacency slabs: skewed-degree graphs stop paying the
-    global max in-degree per node.
+    global max in-degree per node, and a hub's row is split into virtual
+    rows no longer than :data:`repro.sparse.formats.SPLIT_WIDTH`.
 
     ``source`` may be one node id or a sequence of k ids.  A sequence
     returns stacked (n_nodes, k) distances, one column per source; on the
@@ -711,9 +712,10 @@ def pagerank(
     set for all k columns, on ELLPACK the configurations run one by one.
 
     A multi-device ``spec.placement`` (SELL layout only) node-partitions
-    the reverse adjacency; every power step each device scatters the new
-    ranks of its owned nodes and the cross-device ``psum`` assembles the
-    replicated iterate — the rank exchange.  Bare layout keywords are
+    the reverse adjacency; every power step each device adds up the
+    pull-sums of its owned nodes, and the cross-device ``psum`` assembles
+    them for the damping that gives the replicated iterate — the rank
+    exchange.  Bare layout keywords are
     deprecated aliases for the matching spec fields.
     """
     spec = ExecSpec.resolve(

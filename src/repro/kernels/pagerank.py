@@ -8,9 +8,10 @@ gather per adjacency column tile and reduces them.  The contribution vector
 The SELL variants are thin drivers over the batched execution core
 (:mod:`repro.kernels.sell_core`): the power iterate is a stacked (n + 1, k)
 column matrix — one column per (damping, iters) configuration — read
-through the core's in-VMEM lane gather, and only the combine op (damped pull-sum plus dangling mass) lives here.  The
-per-bucket launch + scatter loop is :func:`sell_core.bucketed_node_step`,
-shared with BFS.
+through the core's in-VMEM lane gather, and only the combine op (the
+pull-sum, added up over a split node's virtual rows) and the damping
+epilogue live here.  The per-bucket launch + scatter loop is
+:func:`sell_core.bucketed_node_step`, shared with BFS.
 
 Grid: (n_nodes / vl,).  VL is the node-block width, exactly the paper's
 knob.  Node counts that do not divide ``vl`` are padded internally (and the
@@ -81,13 +82,12 @@ def pagerank_step(
     return out[:n]
 
 
-def _pr_sell_step_kernel(radj_ref, nodes_ref, contrib_ref, consts_ref,
-                         out_ref):
-    """The PageRank combine op: damped pull-sum.
+def _pr_sell_step_kernel(radj_ref, nodes_ref, contrib_ref, out_ref):
+    """The PageRank combine op: the pull-sum of one virtual row.
 
     One output row per stacked (damping, iters) column kk of the
-    (k, R, lanes) contribution table; ``consts_ref`` is the (3, k)
-    [(1-d)/n, d, dangling/n] table in SMEM.
+    (k, R, lanes) contribution table.  A split node's rows are summed by
+    the scatter, and the damping applied after it (:func:`damped`).
     """
     del nodes_ref                             # pull-only: no own-state gather
     width, c = radj_ref.shape[1:]
@@ -101,14 +101,19 @@ def _pr_sell_step_kernel(radj_ref, nodes_ref, contrib_ref, consts_ref,
             return acc + jnp.sum(jnp.where(mask, got, 0.0), axis=0,
                                  keepdims=True)
 
-        pulled = jax.lax.fori_loop(0, width // rows, block,
-                                   jnp.zeros((1, c), out_ref.dtype))
-        base, damping = consts_ref[0, kk], consts_ref[1, kk]
-        out_ref[0, pl.ds(kk, 1), :] = base + damping * (
-            pulled + consts_ref[2, kk])
+        out_ref[0, pl.ds(kk, 1), :] = jax.lax.fori_loop(
+            0, width // rows, block, jnp.zeros((1, c), out_ref.dtype))
         return carry
 
     jax.lax.fori_loop(0, contrib_ref.shape[0], column, 0)
+
+
+def damped(pulled: jnp.ndarray, consts: jnp.ndarray) -> jnp.ndarray:
+    """The power step's epilogue over the (n + 1, k) pull-sums: ``(1-d)/n
+    + d * (pulled + dangling/n)`` per column from the (3, k) ``consts``,
+    the dump slot kept at 0."""
+    out = consts[0] + consts[1] * (pulled + consts[2])
+    return out.at[-1].set(0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -124,17 +129,18 @@ def pagerank_step_sell(
 
     ``contrib`` is (n + 1,) for a single configuration or (n + 1, k) for k
     stacked ones (dump slot = 0); ``consts`` is (3,) or (3, k) to match.
-    The per-bucket results are scattered back to original node order
-    through ``bucket_nodes``; returns the new rank matrix, same shape as
-    ``contrib``.
+    The per-bucket pull-sums are added into original node order through
+    ``bucket_nodes`` and damped in the same program; returns the new rank
+    matrix, same shape as ``contrib``.
     """
     cols = contrib if contrib.ndim == 2 else contrib[:, None]
-    out = sell_core.bucketed_node_step(
+    consts = consts.reshape(3, cols.shape[1])
+    pulled = sell_core.bucketed_node_step(
         _pr_sell_step_kernel, bucket_radj, bucket_nodes,
-        cols, consts.reshape(3, cols.shape[1]), jnp.zeros_like(cols),
+        cols, None, jnp.zeros_like(cols), combine="add",
         interpret=interpret,
     )
-    out = out.at[-1].set(0.0)                 # keep the dump slot inert
+    out = damped(pulled, consts)
     return out if contrib.ndim == 2 else out[:, 0]
 
 

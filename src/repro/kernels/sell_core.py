@@ -21,8 +21,9 @@ is the single device-execution core every SELL-layout kernel drives:
   streams hide the HBM round-trip, so one node hosts million-row operands.
 * :func:`bucketed_node_step` — the shared per-bucket launch + scatter loop
   of the graph kernels: BFS and PageRank supply only their combine kernels
-  (frontier test, damped pull-sum) and their per-step state as stacked
-  (n + 1, k) columns; the slice/scatter plumbing lives here once.
+  (frontier test, pull-sum), the scatter that merges a split node's
+  virtual rows (min, add) and their per-step state as stacked (n + 1, k)
+  columns; the slice/scatter plumbing lives here once.
 
 The gather form.  Every kernel reads X (or the graph state) from VMEM
 through :func:`gather`: the vector is held as a (k, R, lanes) table
@@ -560,22 +561,25 @@ def bucketed_node_step(
     bucket_adj: tuple[jnp.ndarray, ...],
     bucket_nodes: tuple[jnp.ndarray, ...],
     state: jnp.ndarray,
-    scalars: jnp.ndarray,
+    scalars: jnp.ndarray | None,
     out_init: jnp.ndarray,
     *,
+    combine: str,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Run ``kernel`` over every (n_slices_b, C, W_b) bucket and scatter.
 
     ``kernel(adj_ref, nodes_ref, table_ref, scal_ref, out_ref)`` sees one
     (1, W_b, C) adjacency tile (the bucket transposed so C sits on lanes),
-    its (1, 1, C) original-node map, the (n + 1, k) ``state`` columns whole
-    as a :func:`lane_table`, the step's ``scalars`` in SMEM, and writes a
-    (1, k, C) output tile — the per-kernel combine op.  The per-bucket
-    results are scattered back to original node order through the node
-    maps (padding lanes land in the dump slot of ``out_init``, shape
-    (n + 1, k)); this loop is the one copy of the slice/scatter plumbing
-    shared by BFS and PageRank.
+    its (1, 1, C) owning-node map, the (n + 1, k) ``state`` columns whole
+    as a :func:`lane_table`, the step's ``scalars`` in SMEM (no
+    ``scal_ref`` when ``scalars`` is None), and writes a (1, k, C) output
+    tile — the per-kernel combine op.  The per-bucket results are
+    scattered into ``out_init`` (shape (n + 1, k); padding lanes land in
+    its dump slot n) through the node maps with the scatter ``combine``
+    (``"min"`` or ``"add"``), which merges the lanes of a node split into
+    several virtual rows; this loop is the one copy of the slice/scatter
+    plumbing shared by BFS and PageRank.
     """
     interpret = resolve_interpret(interpret)
     out = out_init
@@ -583,6 +587,7 @@ def bucketed_node_step(
     if not bucket_adj:
         return out
     table = lane_table(state, lane_width(bucket_adj[0].shape[1]))
+    smem = () if scalars is None else (scalars,)
     for adj, nodes in zip(bucket_adj, bucket_nodes):
         s, c, w = adj.shape
         res = pl.pallas_call(
@@ -592,13 +597,12 @@ def bucketed_node_step(
                 pl.BlockSpec((1, w, c), lambda i: (i, 0, 0)),
                 pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0)),
                 pl.BlockSpec(table.shape, lambda i: (0, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-            ],
+            ] + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(smem),
             out_specs=pl.BlockSpec((1, k, c), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((s, k, c), out.dtype),
             compiler_params=compiler_params(),
             interpret=interpret,
-        )(adj.transpose(0, 2, 1), nodes.reshape(s, 1, c), table, scalars)
-        out = out.at[nodes.reshape(-1)].set(
-            res.transpose(0, 2, 1).reshape(s * c, k))
+        )(adj.transpose(0, 2, 1), nodes.reshape(s, 1, c), table, *smem)
+        scatter = getattr(out.at[nodes.reshape(-1)], combine)
+        out = scatter(res.transpose(0, 2, 1).reshape(s * c, k))
     return out

@@ -20,8 +20,8 @@ plays within one core.  This module is the device-parallel face of
   whose per-level step runs each device's bucketed node step on its owned
   node range against the replicated state, then combines: BFS unions
   frontiers with ``pmin`` (an update only ever lowers INF to a level),
-  PageRank exchanges ranks with ``psum`` (each node's new rank is written
-  by exactly one owner, zeros elsewhere).
+  PageRank adds the pull-sums with ``psum`` (each node's virtual rows
+  live on its owner, zeros elsewhere) and damps them after.
 
 All mesh plumbing goes through :mod:`repro.compat` (``shard_map``,
 ``MeshContext``, ``make_mesh``); with no concrete multi-device mesh every
@@ -46,7 +46,11 @@ from repro.graphs.gen import ShardedGraphSlabs
 from repro.kernels import sell_core
 from repro.kernels.backend import float_dtype, resolve_interpret
 from repro.kernels.bfs import INF, _bfs_sell_step_kernel
-from repro.kernels.pagerank import _pr_sell_step_kernel, broadcast_configs
+from repro.kernels.pagerank import (
+    _pr_sell_step_kernel,
+    broadcast_configs,
+    damped,
+)
 from repro.obs import trace as obs_trace
 from repro.sparse.formats import SellSlabs, ShardedSlabs
 
@@ -305,55 +309,68 @@ def _rhs_sharded_spmm(cols_t, vals_t, rows_t, x, *, mesh, axis, n_rows,
 
 
 def _graph_step_fn(sg: ShardedGraphSlabs, mesh, kernel, combine: str,
-                   interpret: bool | None):
-    """Build ``step(state, scalars, out_init) -> combined state``.
+                   interpret: bool | None, finish=None):
+    """Build ``step(state, scalars, out_init, consts=None) -> combined
+    state``.
 
     The per-device program is :func:`sell_core.bucketed_node_step` over the
-    shard's buckets — identical to the single-device drivers — followed by
-    the cross-device combine (``combine`` is ``"pmin"`` or ``"psum"``).
-    Serially (no concrete mesh) the matching element-wise op folds over
-    shards, so both paths compute the same values.  The step compiles once
-    per layout and state shape, not once per level.
+    shard's buckets (``scalars`` to the kernel's SMEM, None for none) —
+    identical to the single-device drivers, with the scatter that matches
+    the collective (``min`` for ``"pmin"``, ``add`` for ``"psum"``) —
+    followed by the cross-device combine and, when given,
+    ``finish(combined, consts)`` in the same program.  Serially (no
+    concrete mesh) the matching element-wise op folds over shards, so both
+    paths compute the same values.  The step compiles once per layout and
+    state shape, not once per level.
     """
     m, axis = _mesh_axis(mesh, sg.n_shards)
     adj_t = tuple(jnp.asarray(b) for b in sg.bucket_adj)
     nodes_t = tuple(jnp.asarray(b) for b in sg.bucket_nodes)
     interpret = resolve_interpret(interpret)
 
-    def step(state, scalars, out_init):
+    def step(state, scalars, out_init, consts=None):
         return _node_step_program(
-            adj_t, nodes_t, state, scalars, out_init, kernel=kernel, mesh=m,
-            axis=axis, combine=combine, interpret=interpret)
+            adj_t, nodes_t, state, scalars, out_init, consts, kernel=kernel,
+            mesh=m, axis=axis, combine=combine, finish=finish,
+            interpret=interpret)
 
     return step
 
 
-_SERIAL_COMBINE = {"pmin": jnp.minimum, "psum": jnp.add}
+#: collective -> (node scatter, element-wise op of the serial fold)
+_COMBINES = {"pmin": ("min", jnp.minimum), "psum": ("add", jnp.add)}
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "kernel", "mesh", "axis", "combine", "interpret"))
-def _node_step_program(adj_t, nodes_t, state, scalars, out_init, *, kernel,
-                       mesh, axis, combine, interpret):
+    "kernel", "mesh", "axis", "combine", "finish", "interpret"))
+def _node_step_program(adj_t, nodes_t, state, scalars, out_init, consts, *,
+                       kernel, mesh, axis, combine, finish, interpret):
+    scatter, fold = _COMBINES[combine]
     if mesh is None:
-        acc = None
+        acc = out_init
         for d in range(adj_t[0].shape[0] if adj_t else 0):
             part = sell_core.bucketed_node_step(
                 kernel, tuple(b[d] for b in adj_t),
                 tuple(b[d] for b in nodes_t), state, scalars, out_init,
+                combine=scatter, interpret=interpret)
+            acc = part if d == 0 else fold(acc, part)
+    else:
+        # scalars ride into the shard_map only when the kernel takes them
+        scal = () if scalars is None else (scalars,)
+
+        def body(adjs, nodeses, state, out_init, *scal):
+            part = sell_core.bucketed_node_step(
+                kernel, tuple(b[0] for b in adjs),
+                tuple(b[0] for b in nodeses), state,
+                scal[0] if scal else None, out_init, combine=scatter,
                 interpret=interpret)
-            acc = part if acc is None else _SERIAL_COMBINE[combine](acc, part)
-        return out_init if acc is None else acc
+            return getattr(jax.lax, combine)(part, axis)
 
-    def body(adjs, nodeses, state, scalars, out_init):
-        part = sell_core.bucketed_node_step(
-            kernel, tuple(b[0] for b in adjs), tuple(b[0] for b in nodeses),
-            state, scalars, out_init, interpret=interpret)
-        return getattr(jax.lax, combine)(part, axis)
-
-    return _shard_map(
-        body, mesh, (P(axis), P(axis), P(), P(), P()), P(),
-    )(adj_t, nodes_t, state, scalars, out_init)
+        acc = _shard_map(
+            body, mesh, (P(axis), P(axis), P(), P()) + (P(),) * len(scal),
+            P(),
+        )(adj_t, nodes_t, state, out_init, *scal)
+    return acc if finish is None else finish(acc, consts)
 
 
 def bfs_sell_sharded(
@@ -403,16 +420,18 @@ def pagerank_sell_sharded(
     """PageRank over node-partitioned reverse adjacency: rank exchange by
     ``psum``.
 
-    Each device scatters the new ranks of its owned nodes into zeros; every
-    node is owned exactly once, so the cross-device sum assembles the full
-    replicated iterate — the rank-exchange collective.  Same contract as
+    Each device adds the pull-sums of its owned nodes' virtual rows into
+    zeros; every node is owned by one device, so the cross-device sum
+    assembles the full pull-sums — the rank-exchange collective — which
+    the same program damps into the replicated iterate.  Same contract as
     :func:`repro.kernels.pagerank.pagerank_sell` (scalar config -> (n,),
     broadcast (damping, iters) columns -> (n, k)).
     """
     n = sg.n_nodes
     scalar = np.ndim(damping) == 0 and np.ndim(iters) == 0
     dtype = float_dtype()
-    step = _graph_step_fn(sg, mesh, _pr_sell_step_kernel, "psum", interpret)
+    step = _graph_step_fn(sg, mesh, _pr_sell_step_kernel, "psum", interpret,
+                          finish=damped)
     dampings, iters_arr = broadcast_configs(damping, iters)
     k = len(dampings)
     rank = jnp.full((n, k), 1.0 / n, dtype)
@@ -425,8 +444,7 @@ def pagerank_sell_sharded(
             dangling = jnp.sum(jnp.where(deg == 0, rank, 0.0), axis=0)
             consts = jnp.stack([(1.0 - d) / n, d, dangling / n]).astype(dtype)
             state = jnp.concatenate([contrib, zero_row])
-            new = step(state, consts, jnp.zeros_like(state))
-            new = new.at[-1].set(0.0)[:n]
+            new = step(state, None, jnp.zeros_like(state), consts)[:n]
             active = jnp.asarray(t <= iters_arr)
             rank = jnp.where(active[None, :], new, rank)
     return rank[:, 0] if scalar else rank

@@ -33,6 +33,8 @@ from repro.graphs.gen import (
     graph_to_sell_slabs,
     in_degree,
     shard_graph_slabs,
+    split_rows,
+    split_stats,
 )
 from repro.kernels.backend import float_dtype
 from repro.service.tunecache import OperandSignature, TuneCache, operand_signature
@@ -82,6 +84,10 @@ class RegisteredOperand:
     #: CONTRACT — ``{"c", "top_k", "d_model", "dtype"}`` — that every
     #: submitted routing matrix is preflighted against
     moe: dict | None = None
+    #: graph layout engagement (kind == "graph"): the split width (None
+    #: when no node was split), the nodes split, the virtual rows and the
+    #: padded slots a node step sweeps (:func:`repro.graphs.gen.split_stats`)
+    split: dict | None = None
 
     @property
     def pad_factor(self) -> float:
@@ -265,12 +271,15 @@ class KernelRegistry:
         registry packs the in-neighbours into SELL slabs straight from the
         edge list (:func:`graph_to_sell_slabs` with ``reverse=True``),
         tuned on the in-degree distribution (the row-length law of the pull
-        traffic).  Under a multi-device mesh the same packer builds the
+        traffic).  Rows longer than the split width are packed as virtual
+        rows (:func:`repro.graphs.gen.split_rows`) and tuned as such, so a
+        hub sets no slice's width; a graph without hubs keeps one row per
+        node.  Under a multi-device mesh the same packer builds the
         node-partitioned layout instead — one pack either way.  The tuned
         layout is preflighted from its metadata before anything is packed,
         so a graph whose launch plans fail is refused without building its
         slabs.  The cache key records the float dtype the PageRank state is
-        held in on the device.
+        held in on the device, and that the rows are split.
         """
         dtype = str(np.dtype(float_dtype()))
         from repro.kernels.ops import _sharded_graph_meta, tune_and_pack
@@ -279,7 +288,7 @@ class KernelRegistry:
         sig = operand_signature(graph)
         # the device count keys the packed-layout memo: one graph packs to
         # different slabs on one device and on a mesh
-        key = self.cache.sell_key("graph", sig, device=self.device,
+        key = self.cache.sell_key("graph-split", sig, device=self.device,
                                   dtype=dtype, machine=self.machine,
                                   n_devices=self.n_devices)
         before = self.cache.hits
@@ -302,13 +311,15 @@ class KernelRegistry:
         hinted = (self.cache.candidate_vls_for("pagerank", self.machine.name)
                   or self.cache.candidate_vls_for("bfs", self.machine.name))
         slabs, tuned = tune_and_pack(
-            in_deg, pack, n_cols=graph.n_nodes, machine=self.machine,
-            candidates_c=hinted, cache=self.cache, base_key=key,
+            split_rows(in_deg)[1], pack, n_cols=graph.n_nodes,
+            machine=self.machine, candidates_c=hinted, cache=self.cache,
+            base_key=key,
         )
         op = RegisteredOperand(
             name=name, kind="graph", signature=sig, tuned=tuned,
             slabs=slabs, n=graph.n_nodes,
             tune_was_cached=self.cache.hits > before,
+            split=split_stats(slabs),
         )
         if self.n_devices > 1:
             from repro.kernels.sell_shard import place
@@ -396,10 +407,12 @@ def _matrix_device_arrays(slabs: SellSlabs) -> dict:
 
 def _graph_layout_meta(in_deg: np.ndarray, c: int, sigma: int) -> SlabMeta:
     """Launch metadata of the reverse-graph slabs ``graph_to_sell_slabs``
-    would pack at (c, sigma), from the in-degrees alone."""
+    would pack at (c, sigma), split rows included, from the in-degrees
+    alone."""
     from repro.sparse.formats import next_pow2, sigma_sort_order, slice_widths
 
-    widths = next_pow2(slice_widths(in_deg, sigma_sort_order(in_deg, sigma),
+    lengths = split_rows(in_deg)[1]
+    widths = next_pow2(slice_widths(lengths, sigma_sort_order(lengths, sigma),
                                     c))
     uniq, counts = np.unique(widths, return_counts=True)
     n = len(in_deg)

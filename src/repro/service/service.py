@@ -105,6 +105,7 @@ STATS_KEYS = (
     "sharded_launches",     # launches on the multi-device sharded path
     "moe_dispatch_launches",  # batched MoE combine launches (LM serving)
     "graph_steps",          # BFS levels / PageRank power steps launched
+    "graph_slots",          # padded slots x state columns those steps swept
 )
 
 
@@ -668,6 +669,13 @@ class KernelService(SlotLoop[KernelRequest]):
             for i, req in enumerate(good):
                 req.result = y[:, i]
 
+    def _count_graph_steps(self, operand, steps: int, columns: int) -> None:
+        """Node steps a graph launch ran, and the slots they swept: every
+        step reads each padded slot once per state column."""
+        self.stats["graph_steps"] += steps
+        self.stats["graph_slots"] += (
+            steps * operand.split["padded_slots"] * int(columns))
+
     def _run_bfs(self, operand, reqs):
         """The whole group is one batched drive: sources become frontier
         columns, every level is a single launch set."""
@@ -708,7 +716,8 @@ class KernelService(SlotLoop[KernelRequest]):
                     interpret=self.interpret,
                 )
         dist = self._fetched(operand, "bfs", dist, sw)
-        self.stats["graph_steps"] += bfs_k.levels_run(dist)
+        self._count_graph_steps(operand, bfs_k.levels_run(dist),
+                                np.size(batch))
         with obs_trace.phase("svc.split"):
             if len(good) == 1:
                 good[0].result = dist
@@ -757,7 +766,7 @@ class KernelService(SlotLoop[KernelRequest]):
                     interpret=self.interpret,
                 )
         rank = self._fetched(operand, "pagerank", rank, sw)
-        self.stats["graph_steps"] += int(np.max(iters))
+        self._count_graph_steps(operand, int(np.max(iters)), np.size(iters))
         with obs_trace.phase("svc.split"):
             if len(good) == 1:
                 good[0].result = rank

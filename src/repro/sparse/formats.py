@@ -325,6 +325,15 @@ def pow2_ceil(x: int) -> int:
 #: be a multiple of it or span the whole array axis
 SUBLANES = 8
 
+#: longest row of the graph SELL layout: a node of higher degree is packed
+#: as near-equal virtual rows of at most this many entries
+#: (:func:`repro.graphs.gen.split_rows`), so no slice is padded to a hub's
+#: degree.  On the Graph500 scale-14 graph, wider rows leave more padding
+#: (800 lane-gather blocks a step at 256 against 664 at 64); narrower ones
+#: cut under a tenth more while the virtual rows multiply (21,750 at 64,
+#: 76,229 at 8), and each row costs an output lane and a scatter update
+SPLIT_WIDTH = 64
+
 
 def k_tile_for(k: int, k_block: int) -> int:
     """The RHS tile one SpMM grid cell processes.

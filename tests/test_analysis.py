@@ -318,6 +318,86 @@ def test_slab_meta_from_graph_slabs():
     assert plan_bfs_sell(meta, k=4).ok
 
 
+@pytest.mark.parametrize("graph", ["hub-free", "rmat", "hub"])
+def test_graph_layout_meta_prices_the_split_slabs(graph):
+    """The preflight's metadata, from the in-degrees alone, is the
+    metadata of the slabs the packer builds, split rows included."""
+    from repro.graphs.gen import (
+        PAD,
+        EllpackGraph,
+        graph_to_sell_slabs,
+        in_degree,
+        random_graph,
+        rmat_graph,
+        split_stats,
+    )
+    from repro.service.registry import _graph_layout_meta
+
+    if graph == "hub-free":
+        g = random_graph(512, avg_degree=6, seed=3)
+    else:
+        g = rmat_graph(512, avg_degree=8, seed=3)
+    if graph == "hub":                         # node 7: in-degree >= 511
+        adj = np.concatenate([g.adj, np.full((512, 1), PAD, np.int32)], 1)
+        adj[np.arange(512) != 7, -1] = 7
+        g = EllpackGraph(adj=adj, n_nodes=512)
+    slabs = graph_to_sell_slabs(g, c=32, sigma=128, reverse=True)
+    assert (split_stats(slabs)["split_nodes"] > 0) == (graph != "hub-free")
+    assert _graph_layout_meta(in_degree(g), 32, 128) == \
+        SlabMeta.from_slabs(slabs)
+
+
+def _graph500_in_degree(scale: int) -> np.ndarray:
+    """In-degrees of the benchmark's Graph500 graph (Kronecker A, B, C =
+    0.57, 0.19, 0.19, edge factor 16, undirected, self-loops out), from
+    its edge list: the degree-padded input would take gigabytes."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(__file__), "..", "bench",
+                        "configs", "graph500-s14.py")
+    spec = importlib.util.spec_from_file_location("graph500_config", path)
+    config = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(config)
+    n = 1 << scale
+    _, dst = config.undirected(
+        config.kronecker_edges(scale, 16, 0.57, 0.19, 0.19, 0), n)
+    return np.bincount(dst, minlength=n)
+
+
+def test_graph500_scale15_fits_once_hub_rows_split():
+    """Unsplit, the scale-15 hub slice is refused (its tile exceeds
+    VMEM); with the hub rows split, as the registry tunes and packs
+    them, every bucket fits at the chip's float32."""
+    from repro.graphs.gen import split_rows
+    from repro.kernels.ops import cached_tune_sell
+    from repro.service.registry import KernelRegistry, _graph_layout_meta
+    from repro.sparse.formats import (
+        SPLIT_WIDTH,
+        next_pow2,
+        sigma_sort_order,
+        slice_widths,
+    )
+
+    in_deg = _graph500_in_degree(15)
+    assert in_deg.max() > SPLIT_WIDTH
+    reg = KernelRegistry(device="tpu")
+    tuned = cached_tune_sell(split_rows(in_deg)[1], n_cols=len(in_deg),
+                             machine=reg.machine)
+    meta = _graph_layout_meta(in_deg, tuned.c, tuned.sigma)
+    assert plan_bfs_sell(meta).ok
+    assert plan_pagerank_sell(meta, dtype="float32").ok
+    assert max(meta.widths) <= SPLIT_WIDTH
+    # the same (C, sigma) with one row per node, as before the split
+    widths = next_pow2(slice_widths(
+        in_deg, sigma_sort_order(in_deg, tuned.sigma), tuned.c))
+    uniq, counts = np.unique(widths, return_counts=True)
+    whole = SlabMeta(
+        kind="graph", c=tuned.c, widths=tuple(int(w) for w in uniq),
+        n_slices=tuple(int(s) for s in counts), n_rows=len(in_deg),
+        n_cols=len(in_deg), val_dtype=None, idx_dtype="int32")
+    assert not plan_pagerank_sell(whole, dtype="float32").ok
+
+
 def test_slab_meta_rejects_unknown_container():
     with pytest.raises(TypeError, match="SellSlabs"):
         SlabMeta.from_slabs(object())

@@ -215,6 +215,92 @@ def test_pagerank_sell_mass_conserved_on_skewed_graph():
     assert (got > 0).all()
 
 
+def _hub_graph(hubs=(3,), seed=5):
+    """An R-MAT digraph on 256 nodes plus an edge from every other node to
+    each of ``hubs`` (in-degree about 255 each, split into virtual rows);
+    with no hubs, every in-degree stays at or below the split width."""
+    g = G.rmat_graph(n_nodes=256, avg_degree=6, seed=seed)
+    n = g.n_nodes
+    if not hubs:
+        g = G.random_graph(n_nodes=n, avg_degree=6, seed=seed)
+        assert G.in_degree(g).max() <= F.SPLIT_WIDTH
+        return g
+    extra = np.full((n, len(hubs)), G.PAD, np.int32)
+    for j, hub in enumerate(hubs):
+        extra[np.arange(n) != hub, j] = hub
+    adj = np.concatenate([g.adj, extra], axis=1)
+    return G.EllpackGraph(adj=adj, n_nodes=n)
+
+
+def _device_slabs(g, c=16, sigma=64):
+    import jax.numpy as jnp
+
+    slabs = G.graph_to_sell_slabs(g, c=c, sigma=sigma, reverse=True)
+    return (tuple(jnp.asarray(a) for a in slabs.bucket_adj),
+            tuple(jnp.asarray(m) for m in slabs.bucket_nodes))
+
+
+#: no hub (one row per node), one hub, three hubs
+HUBS = [(), (3,), (3, 40, 200)]
+
+
+@pytest.mark.parametrize("hubs", HUBS, ids=["hub-free", "hub", "hubs3"])
+@pytest.mark.parametrize("k", [1, 8])
+def test_bfs_sell_split_layout_matches_host_bfs(hubs, k):
+    """A hub split into virtual rows merges by minimum: distances equal
+    the host BFS for one source and for 8 stacked ones."""
+    from repro.kernels import bfs as bfs_k
+
+    g = _hub_graph(hubs)
+    sources = [1, 3, 7, 40, 100, 150, 200, 255][:k]
+    adj, nodes = _device_slabs(g)
+    got = np.asarray(bfs_k.bfs_sell(
+        adj, nodes, g.n_nodes, sources[0] if k == 1 else sources))
+    want = np.stack([G.bfs_reference(g, s) for s in sources], axis=1)
+    np.testing.assert_array_equal(got, want[:, 0] if k == 1 else want)
+
+
+@pytest.mark.parametrize("hubs", HUBS, ids=["hub-free", "hub", "hubs3"])
+@pytest.mark.parametrize("configs", [(0.85, 12), ((0.85, 0.6), (12, 7))])
+def test_pagerank_sell_split_layout_matches_reference(hubs, configs):
+    """A hub's pull-sum added up over its virtual rows, then damped: the
+    ranks agree with the float64 reference (x64 on the CPU, hence the
+    tight tolerance) for one config and for stacked ones."""
+    import jax.numpy as jnp
+
+    from repro.kernels import pagerank as pr_k
+
+    g = _hub_graph(hubs, seed=6)
+    damping, iters = configs
+    adj, nodes = _device_slabs(g)
+    got = np.asarray(pr_k.pagerank_sell(
+        adj, nodes, jnp.asarray(g.out_degree, jnp.float64), g.n_nodes,
+        damping=damping, iters=iters))
+    want = np.stack([G.pagerank_reference(g, damping=d, iters=i)
+                     for d, i in zip(*pr_k.broadcast_configs(damping, iters))],
+                    axis=1)
+    np.testing.assert_allclose(
+        got, want[:, 0] if np.ndim(damping) == 0 else want, rtol=1e-10)
+
+
+@pytest.mark.parametrize("hubs", HUBS[1:], ids=["hub", "hubs3"])
+def test_ops_sell_path_splits_hub_rows(hubs):
+    """``ops.bfs``/``ops.pagerank`` on the SELL layout pack the split rows
+    themselves (no registry): stacked BFS and PageRank equal the host
+    references."""
+    g = _hub_graph(hubs, seed=7)
+    slabs = G.graph_to_sell_slabs(g, c=32, reverse=True)
+    assert G.split_stats(slabs)["split_nodes"] >= len(hubs)
+    assert max(slabs.widths) <= F.SPLIT_WIDTH
+    sources = [0, 3, 99]
+    np.testing.assert_array_equal(
+        ops.bfs(g, sources, vl=32, layout="sell"),
+        np.stack([G.bfs_reference(g, s) for s in sources], axis=1))
+    np.testing.assert_allclose(
+        ops.pagerank(g, iters=9, vl=32, layout="sell"),
+        G.pagerank_reference(g, iters=9), rtol=1e-10)
+
+
 def test_graph_sell_slabs_pad_less_on_skewed_degrees():
     g = G.rmat_graph(n_nodes=1 << 10, avg_degree=8, seed=0)
     rg = g.transpose()

@@ -606,6 +606,70 @@ def test_service_coalesces_pagerank_configs_into_one_batched_drive(
         rtol=1e-8)
 
 
+def _hub_graph(n=200, hub=9):
+    g = G.random_graph(n_nodes=n, avg_degree=5, seed=4)
+    adj = np.concatenate([g.adj, np.full((n, 1), G.PAD, np.int32)], axis=1)
+    adj[np.arange(n) != hub, -1] = hub
+    return G.EllpackGraph(adj=adj, n_nodes=n)
+
+
+def test_registry_splits_hub_rows_and_records_it():
+    """The hub's in-degree (about 200) is cut to the split width: the
+    operand records the width, the split nodes, the virtual rows and the
+    padded slots, and serves exact answers from the split layout."""
+    from repro.sparse.formats import SPLIT_WIDTH
+
+    graph = _hub_graph()
+    in_deg = G.in_degree(graph)
+    reg = KernelRegistry()
+    op = reg.register_graph("g", graph)
+    width = op.split["split_width"]
+    assert width == SPLIT_WIDTH < in_deg.max()
+    assert op.split == G.split_stats(op.slabs)
+    assert op.split["split_nodes"] == int((in_deg > width).sum())
+    assert op.split["virtual_rows"] == int(
+        np.maximum(-(-in_deg // width), 1).sum())
+    assert op.split["padded_slots"] == op.slabs.padded_entries
+    assert max(op.slabs.widths) <= width
+    svc = KernelService(reg, n_slots=4)
+    rids = [svc.submit("bfs", "g", source=s) for s in (0, 9, 17)]
+    rp = svc.submit("pagerank", "g", iters=5)
+    svc.drain()
+    for rid, s in zip(rids, (0, 9, 17)):
+        np.testing.assert_array_equal(svc.poll(rid), G.bfs_reference(graph, s))
+    np.testing.assert_allclose(
+        svc.poll(rp), G.pagerank_reference(graph, iters=5), rtol=1e-10)
+    # three sources ride one pow2-padded 4-column drive, PageRank one column
+    levels = svc.stats["graph_steps"] - 5
+    assert svc.stats["graph_slots"] == op.split["padded_slots"] * (
+        4 * levels + 5)
+
+
+def test_graph_tune_without_split_width_is_never_served(count_measures):
+    """A graph tune keyed the way unsplit layouts were keyed is never
+    read: the split layout tunes under its own key, and a second
+    registration hits it."""
+    from repro.core.autotune import SellTuneResult
+
+    graph = _hub_graph()
+    cache = TuneCache()
+    reg = KernelRegistry(cache=cache)
+    old_key = cache.sell_key("graph", graph, device=reg.device,
+                             machine=reg.machine)
+    cache.put_sell(old_key, SellTuneResult(
+        c=8, sigma=8, w_block=8, cycles=1.0, pad_factor=1.0,
+        table=((8, 8, 1.0, 1.0),)))
+    op = reg.register_graph("g", graph)
+    assert not op.tune_was_cached and count_measures["n"] > 0
+    assert cache._entries[old_key]["hits"] == 0
+    (key,) = [k for k in cache._entries if k != old_key]
+    assert key.startswith("graph-split|")
+    count_measures["n"] = 0
+    again = KernelRegistry(cache=cache).register_graph("g2", graph)
+    assert again.tune_was_cached and count_measures["n"] == 0
+    assert again.slabs is op.slabs             # the packed-layout memo
+
+
 def test_service_bad_spmv_payload_excluded_from_batched_launch(small_world):
     """A wrong-sized x fails alone; its groupmates ride the same batched
     launch and succeed (the stacking must skip the bad column)."""

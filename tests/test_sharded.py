@@ -128,6 +128,42 @@ def test_graph_sharded_serial_matches_unsharded():
     np.testing.assert_allclose(got_pr, ref_pr, atol=1e-10)
 
 
+@pytest.mark.parametrize("n_shards", [2, 3])
+def test_graph_sharded_serial_split_matches_single_device(n_shards):
+    """Hub rows split into virtual rows inside their owner's shard: the
+    serial fold (min for BFS, sum then damping for PageRank) equals the
+    single-device split layout, and the host references."""
+    import jax.numpy as jnp
+
+    from repro.kernels import bfs as bfs_k
+    from repro.kernels import pagerank as pr_k
+
+    g = G.rmat_graph(n_nodes=128, avg_degree=5, seed=9)
+    adj = np.concatenate([g.adj, np.full((128, 1), G.PAD, np.int32)], axis=1)
+    adj[1:, -1] = 0                           # node 0: in-degree >= 127
+    g = G.EllpackGraph(adj=adj, n_nodes=128)
+    slabs = G.graph_to_sell_slabs(g, c=16, reverse=True)
+    assert G.split_stats(slabs)["split_nodes"] >= 1
+    dev = (tuple(jnp.asarray(a) for a in slabs.bucket_adj),
+           tuple(jnp.asarray(m) for m in slabs.bucket_nodes))
+    deg = jnp.asarray(g.out_degree, jnp.float64)
+    sources = [0, 5, 77]
+    ref_bfs = np.asarray(bfs_k.bfs_sell(*dev, g.n_nodes, sources))
+    ref_pr = np.asarray(pr_k.pagerank_sell(
+        *dev, deg, g.n_nodes, damping=[0.85, 0.7], iters=[10, 6]))
+    sg = G.shard_graph_slabs(g, c=16, n_shards=n_shards, reverse=True)
+    got_bfs = np.asarray(sell_shard.bfs_sell_sharded(sg, sources, mesh=None))
+    got_pr = np.asarray(sell_shard.pagerank_sell_sharded(
+        sg, deg, damping=[0.85, 0.7], iters=[10, 6], mesh=None))
+    assert np.array_equal(got_bfs, ref_bfs)
+    np.testing.assert_array_equal(
+        ref_bfs, np.stack([G.bfs_reference(g, s) for s in sources], axis=1))
+    np.testing.assert_allclose(got_pr, ref_pr, atol=1e-12)
+    np.testing.assert_allclose(
+        ref_pr[:, 1], G.pagerank_reference(g, damping=0.7, iters=6),
+        rtol=1e-10)
+
+
 def test_ops_placement_one_is_single_device():
     """placement=1 resolves to the empty mesh: the plain resident path."""
     csr = F.random_csr(50, 50, 4.0, seed=8)
@@ -181,6 +217,14 @@ WORKER_COMMON = textwrap.dedent(
     from repro.kernels.execspec import ExecSpec
     from repro.sparse import formats as F
     rng = np.random.default_rng(0)
+
+    def with_hub(g, hub):
+        # an edge from every other node to ``hub``: an in-degree above
+        # the split width, so the hub's row is split into virtual rows
+        n = g.n_nodes
+        adj = np.concatenate([g.adj, np.full((n, 1), G.PAD, np.int32)], 1)
+        adj[np.arange(n) != hub, -1] = hub
+        return G.EllpackGraph(adj=adj, n_nodes=n)
     """
 )
 
@@ -193,7 +237,9 @@ def test_sharded_ops_match_single_device(n_devices):
         csr = F.random_csr(131, 131, 5.0, seed=1, skew=1.5)
         x = rng.standard_normal(131)
         xb = rng.standard_normal((131, 8))
-        g = G.random_graph(n_nodes=90, avg_degree=4, seed=2)
+        g = with_hub(G.random_graph(n_nodes=90, avg_degree=4, seed=2), 7)
+        sg = ops._shard_graph_cached(g, 16, None, N, None)
+        assert G.split_stats(sg)["split_nodes"] == 1
         spec = ExecSpec(vl=16, placement=N)
         gspec = ExecSpec(vl=16, placement=N, layout="sell")
         errs = {
@@ -207,6 +253,13 @@ def test_sharded_ops_match_single_device(n_devices):
             "bfs": float(np.abs(
                 np.asarray(ops.bfs(g, 3, spec=gspec)).astype(np.int64)
                 - np.asarray(ops.bfs(g, 3, vl=16)).astype(np.int64)).max()),
+            "bfs_host": float(np.abs(
+                np.asarray(ops.bfs(g, [3, 7], spec=gspec)).astype(np.int64)
+                - np.stack([G.bfs_reference(g, s) for s in (3, 7)],
+                           axis=1)).max()),
+            "pagerank_host": float(np.abs(
+                np.asarray(ops.pagerank(g, iters=10, spec=gspec))
+                - G.pagerank_reference(g, iters=10)).max()),
         }
         # empty per-device buckets: 10 rows, one dense, over N devices
         small = F.random_csr(10, 10, 1.2, seed=3, skew=2.0)
@@ -235,7 +288,7 @@ def test_sharded_service_matches_single_device(n_devices):
         from repro.service import (KernelRegistry, KernelService,
                                    SubmitRequest, TuneCache)
         csr = F.random_csr(101, 101, 5.0, seed=4, skew=1.0)
-        g = G.random_graph(n_nodes=80, avg_degree=4, seed=5)
+        g = with_hub(G.random_graph(n_nodes=80, avg_degree=4, seed=5), 2)
         xs = [rng.standard_normal(101) for _ in range(3)]
 
         def serve(mesh):
@@ -254,6 +307,8 @@ def test_sharded_service_matches_single_device(n_devices):
         ys1, bfs1, pr1, _ = serve(None)
         ysN, bfsN, prN, svc = serve(N)
         assert svc.registry.get("mat").mode == "sharded"
+        assert svc.registry.get("graph").mode == "sharded"
+        assert svc.registry.get("graph").split["split_nodes"] == 1
         assert svc.stats["sharded_launches"] >= 2, svc.stats
         print(json.dumps({
             "spmv": max(float(np.abs(a - b).max())

@@ -32,7 +32,7 @@ from repro.kernels import pagerank as pr_k
 from repro.sparse import formats as F
 
 #: R-MAT scale of the compiled graph steps: the largest chip_smoke serves
-GRAPH_NODES = 1 << 17
+GRAPH_NODES = 1 << 18
 
 
 @pytest.fixture(scope="module")
