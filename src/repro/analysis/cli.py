@@ -29,6 +29,7 @@ def _self_check_plans(out=sys.stdout) -> int:
         SlabMeta,
         plan_bfs_sell,
         plan_fft_stockham,
+        plan_ft,
         plan_moe_dispatch,
         plan_pagerank_sell,
         plan_spmm_sell,
@@ -70,6 +71,7 @@ def _self_check_plans(out=sys.stdout) -> int:
         plan_bfs_sell(gm, k=8),
         plan_pagerank_sell(gm, k=8),
         plan_fft_stockham(n=1024, batch=32),
+        plan_ft((512, 512, 512), dtype="float32"),
         plan_moe_dispatch(rm, k=64, x_dtype="float64", top_k=2),
     ]
     bad = 0
@@ -98,13 +100,21 @@ def _self_check_plans(out=sys.stdout) -> int:
         print("EXPECTED-REJECT FAILED: moe_dispatch plan accepted a "
               f"non-routing operand {mat.describe()}", file=out)
         bad += 1
+    # NPB FT class D (2048 x 1024 x 1024) holds 17 GB of spectrum alone:
+    # the plan must refuse it on one chip's HBM
+    class_d = plan_ft((2048, 1024, 1024), dtype="float32")
+    if class_d.ok:
+        print("EXPECTED-REJECT FAILED: ft plan accepted NPB FT class D "
+              "on one chip", file=out)
+        bad += 1
     if not accept.ok:
         bad += 1
     else:
         plans.append(accept)
     print(f"launch-plan self-check: {len(plans) - bad}/{len(plans)} ok "
-          "(+ giant-operand resident rejection and non-routing "
-          "moe_dispatch rejection proved)",
+          "(+ giant-operand resident rejection, non-routing "
+          "moe_dispatch rejection and NPB FT class D HBM rejection "
+          "proved)",
           file=out)
     return bad
 

@@ -4,7 +4,8 @@ Each ``plan_*`` function mirrors the launch arithmetic of its kernel wrapper
 (:func:`repro.kernels.sell_core.spmm_sell`,
 :func:`repro.kernels.sell_core.spmm_sell_stream`,
 :func:`repro.kernels.sell_core.bucketed_node_step` as driven by the BFS /
-PageRank kernels, :func:`repro.kernels.fft.fft_stockham`) without importing
+PageRank kernels, :func:`repro.kernels.fft.fft_stockham` and the NPB FT
+step's passes of it) without importing
 or executing any of them: the grid dims, block shapes and per-cell VMEM
 footprints are derived from operand *metadata* (:class:`SlabMeta`) and the
 tuned tile sizes alone.  The footprint model matches the one
@@ -23,7 +24,9 @@ Checked contracts:
 * column/adjacency index bounds: every stored index in [PAD, n_cols)
   (``SlabMeta.from_slabs(check_bounds=True)`` scans once, at registration);
 * dtype flow: slab buckets agree with each other and with the RHS; indices
-  are int32.
+  are int32;
+* HBM (NPB FT, :func:`plan_ft`): the resident spectrum and a group's
+  transient fields fit the chip's memory.
 """
 from __future__ import annotations
 
@@ -47,9 +50,12 @@ from repro.sparse.formats import (
 )
 
 __all__ = [
+    "FtPlan",
+    "HBM_BUDGET_BYTES",
     "SlabMeta",
     "plan_bfs_sell",
     "plan_fft_stockham",
+    "plan_ft",
     "plan_moe_dispatch",
     "plan_pagerank_sell",
     "plan_spmm_sell",
@@ -59,6 +65,16 @@ __all__ = [
 
 _IDX_BYTES = 4                       # int32 column / adjacency indices
 _LANES = 128                         # lanes of a TPU vreg
+
+#: HBM of one TPU v5e chip: 16 GB, its published capacity
+HBM_BUDGET_BYTES = 16 * 10**9
+#: whole fields (re and im planes) one NPB FT step holds beside the
+#: resident spectrum, per field in its group: a pass's input and output,
+#: and the evolve factor (2.5 fields a member in the compiled program's
+#: temporaries at 512^3 on a v5e), rounded up
+FT_TRANSIENT_FIELDS = 3
+#: widest signal block of an NPB FT pass
+FT_B_BLOCK_MAX = 256
 
 
 def _dtype_bytes(dtype: str) -> int:
@@ -579,3 +595,78 @@ def plan_fft_stockham(
         violations=tuple(violations),
     )
     return plan
+
+
+@dataclasses.dataclass(frozen=True)
+class FtPlan(LaunchPlan):
+    """:class:`LaunchPlan` of one NPB FT step, with what the program takes
+    from it: the signal block of the x, y and z passes, the widest group of
+    steps one launch may stack (a power of two, 0 when not even one fits),
+    and the HBM the plan's group holds."""
+
+    b_blocks: tuple[int, int, int] = (1, 1, 1)
+    max_group: int = 0
+    hbm_bytes: int = 0
+    hbm_budget: int = HBM_BUDGET_BYTES
+
+
+def _ft_b_block(n: int, batch: int, dtype: str, vmem_budget: int) -> int:
+    """Widest power-of-two block of ``batch`` length-``n`` signals, at most
+    :data:`FT_B_BLOCK_MAX`, that divides the batch and fits VMEM."""
+    bb = 1
+    while (bb * 2 <= min(batch, FT_B_BLOCK_MAX) and batch % (bb * 2) == 0
+           and plan_fft_stockham(n, batch, b_block=bb * 2, dtype=dtype,
+                                 vmem_budget=vmem_budget).ok):
+        bb *= 2
+    return bb
+
+
+def plan_ft(
+    shape: tuple[int, int, int],
+    k: int = 1,
+    *,
+    dtype: str = "float32",
+    hbm_budget: int = HBM_BUDGET_BYTES,
+    vmem_budget: int = VMEM_BUDGET_BYTES,
+) -> FtPlan:
+    """Plan one NPB FT step (:func:`repro.kernels.fft.ft_step`) of ``k``
+    stacked time steps on an (nx, ny, nz) field: one ``fft_stockham`` pass
+    per axis over k * N / n signals of that axis's length n, each at the
+    widest block that divides one field's N / n and fits VMEM (so every
+    group width of the field runs the same blocks); and the chip's HBM,
+    which holds the resident spectrum (two planes of the field) and
+    :data:`FT_TRANSIENT_FIELDS` fields per stacked step."""
+    violations: list[str] = []
+    shape = tuple(int(n) for n in shape)
+    if len(shape) != 3:
+        violations.append(f"ft field must be 3-D, got shape {shape}")
+        shape = (shape + (1, 1, 1))[:3]
+    if k < 1:
+        violations.append(f"group width must be >= 1, got {k}")
+    total = math.prod(shape)
+    field = 2 * total * _dtype_bytes(dtype)
+    b_blocks, blocks = [], []
+    for axis, n in enumerate(shape):
+        # a block that divides one field's lines divides a group's too
+        lines = total // max(n, 1)
+        bb = _ft_b_block(n, lines, dtype, vmem_budget)
+        sub = plan_fft_stockham(n, max(int(k), 1) * lines, b_block=bb,
+                                dtype=dtype, vmem_budget=vmem_budget)
+        violations.extend(f"axis {axis}: {v}" for v in sub.violations)
+        b_blocks.append(bb)
+        blocks.append(dataclasses.replace(
+            sub.blocks[0], label=f"axis{axis}[n={n}]"))
+    hbm = field + max(int(k), 1) * FT_TRANSIENT_FIELDS * field
+    if hbm > hbm_budget:
+        violations.append(
+            f"resident spectrum {field} B + {k} x {FT_TRANSIENT_FIELDS} "
+            f"transient fields of {field} B = {hbm} B exceeds HBM budget "
+            f"{hbm_budget} B (field {shape})")
+    room = (hbm_budget - field) // (FT_TRANSIENT_FIELDS * field)
+    return FtPlan(
+        kernel="ft_step", operand=f"ft {shape[0]}x{shape[1]}x{shape[2]} "
+        f"k={k}", dtype=dtype, vmem_budget=int(vmem_budget),
+        blocks=tuple(blocks), violations=tuple(violations),
+        b_blocks=tuple(b_blocks),
+        max_group=1 << (room.bit_length() - 1) if room >= 1 else 0,
+        hbm_bytes=int(hbm), hbm_budget=int(hbm_budget))

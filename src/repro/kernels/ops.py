@@ -19,6 +19,7 @@ from repro.analysis.preflight import (
     SlabMeta,
     plan_bfs_sell,
     plan_fft_stockham,
+    plan_ft,
     plan_moe_dispatch,
     plan_pagerank_sell,
     plan_spmm_sell,
@@ -605,6 +606,38 @@ def fft(
         dtype=str(re.dtype),
     ).raise_if_invalid()
     return fft_k.fft_stockham(re, im, wre, wim, b_block=bb, interpret=interp)
+
+
+def ft(
+    re: np.ndarray | jnp.ndarray,
+    im: np.ndarray | jnp.ndarray,
+    steps,
+    alpha: float,
+    *,
+    spec: ExecSpec | None = None,
+) -> jnp.ndarray:
+    """NPB FT time steps ``steps`` of the (nx, ny, nz) field ``re + i im``
+    with diffusion constant ``alpha``: (len(steps), 1025) complex, u_t at
+    the 1024 checksum points and last the checksum
+    (:func:`repro.kernels.fft.ft_step`).  Runs the forward transform on
+    every call; :meth:`repro.service.KernelRegistry.register_ft` keeps the
+    spectrum resident instead.  Configuration rides on ``spec=``
+    (``interpret``); the plan (:func:`plan_ft`) picks the passes' blocks."""
+    spec = ExecSpec.resolve(spec, _caller="ops.ft")
+    if spec.n_devices() > 1:
+        raise ValueError(
+            "ft has no sharded execution path; use a single-device "
+            "placement")
+    re, im = jnp.asarray(re), jnp.asarray(im)
+    steps = np.atleast_1d(np.asarray(steps, np.int64))
+    plan = plan_ft(re.shape, k=len(steps),
+                   dtype=str(re.dtype)).raise_if_invalid()
+    interp = resolve_interpret(spec.interpret)
+    sre, sim = fft_k.ft_spectrum(re, im, b_blocks=plan.b_blocks,
+                                 interpret=interp)
+    decay = jnp.asarray(fft_k.ft_decay(alpha, steps).astype(re.dtype))
+    return fft_k.ft_step(sre, sim, decay, b_blocks=plan.b_blocks,
+                         interpret=interp)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 PAD = -1
@@ -95,6 +96,40 @@ def fft_ref(re: jnp.ndarray, im: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]
     """Ground-truth spectrum via jnp.fft (oracle for the oracle)."""
     spec = jnp.fft.fft(re + 1j * im)
     return jnp.real(spec), jnp.imag(spec)
+
+
+# ---------------------------------------------------------------------------
+# NPB FT (the 3-D heat equation, solved spectrally)
+# ---------------------------------------------------------------------------
+
+
+def ft_ref(re: jnp.ndarray, im: jnp.ndarray, t: int, alpha: float
+           ) -> jnp.ndarray:
+    """NPB FT's field at time step ``t`` (``ft.f``: ``compute_indexmap``,
+    ``evolve``, ``fft``): u_t = IFFT3(FFT3(re + i im) * exp(-4 alpha pi^2
+    t kbar^2)), kbar = ((k + n/2) mod n) - n/2 per axis, the forward
+    transform e^{-2 pi i}, the inverse e^{+2 pi i} and unnormalised.  The
+    evolve factor is raised to t at once, which NPB reaches by t
+    multiplications."""
+    with jax.default_matmul_precision("highest"):
+        u0 = jnp.fft.fftn(re + 1j * im)
+        k2 = 0
+        for axis, n in enumerate(u0.shape):
+            k = jnp.arange(n)
+            kbar = (k + n // 2) % n - n // 2
+            k2 = k2 + jnp.expand_dims(kbar * kbar, [a for a in range(3)
+                                                    if a != axis])
+        return jnp.fft.ifftn(u0 * jnp.exp(-4.0 * alpha * np.pi ** 2 * t * k2),
+                             norm="forward")
+
+
+def ft_checksum_ref(u: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """NPB FT's ``checksum`` of a field: the 1024 values u[j mod nx, 3j mod
+    ny, 5j mod nz], j = 1..1024, and their sum over nx ny nz."""
+    nx, ny, nz = u.shape
+    j = np.arange(1, 1025)
+    samples = u[j % nx, (3 * j) % ny, (5 * j) % nz]
+    return samples, samples.sum() / u.size
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,11 @@ only when switched on:
   as a child of the current span, so phases share the request trees' ids.
 
 With both off, :func:`phase` returns one shared null context: no clock
-read, no allocation.  The switches are process-wide, like
+read, no allocation.
+
+:func:`scope` marks a phase inside one jitted program (``ft.pass``), where
+a host phase cannot: the program's Python body runs once, while it is
+traced.  Its operations carry the name on the device.  The switches are process-wide, like
 :func:`repro.obs.profile.install`: the serving loop is single-threaded.
 """
 from __future__ import annotations
@@ -40,7 +44,7 @@ from collections import deque
 from repro.obs import timer
 
 __all__ = ["Span", "Tracer", "annotate", "annotating", "children_of",
-           "phase"]
+           "phase", "scope"]
 
 
 @dataclasses.dataclass(slots=True)
@@ -285,3 +289,14 @@ def children_of(tracer: Tracer | None, span: Span | None):
     if tracer is None or span is None:
         return _NULL
     return _parented(tracer, span)
+
+
+def scope(name: str, **attrs):
+    """A phase of a jitted program: a ``jax.named_scope`` over the
+    operations traced inside it, so each carries the phase, with its
+    attributes (``ft.pass/axis=2``), in its metadata and in the profiler's
+    device events."""
+    import jax
+
+    return jax.named_scope("/".join(
+        [name] + [f"{k}={v}" for k, v in attrs.items()]))

@@ -19,6 +19,7 @@ from repro.analysis.preflight import (
     SlabMeta,
     plan_bfs_sell,
     plan_fft_stockham,
+    plan_ft,
     plan_moe_dispatch,
     plan_pagerank_sell,
     plan_spmm_sell,
@@ -28,6 +29,7 @@ from repro.analysis.preflight import (
 from repro.core.autotune import SellTuneResult
 from repro.core.sdv import MachineParams, tpu_v5e_machine
 from repro.obs import MetricsRegistry, Stopwatch
+from repro.obs import trace as obs_trace
 from repro.graphs.gen import (
     EllpackGraph,
     graph_to_sell_slabs,
@@ -59,12 +61,12 @@ class RegisteredOperand:
     """
 
     name: str
-    kind: str                               # matrix | graph | fft
+    kind: str                               # matrix | graph | fft | ft | moe
     signature: OperandSignature | None
     tuned: SellTuneResult | None = None
     slabs: Any = None                       # SellSlabs | SellGraphSlabs
     device_arrays: dict = dataclasses.field(default_factory=dict)
-    n: int = 0                              # n_rows / n_nodes / fft length
+    n: int = 0                  # n_rows / n_nodes / fft length / ft points
     n_cols: int = 0                         # RHS length for matrix operands
     register_us: float = 0.0                # wall time spent registering
     tune_was_cached: bool = False
@@ -84,6 +86,10 @@ class RegisteredOperand:
     #: CONTRACT — ``{"c", "top_k", "d_model", "dtype"}`` — that every
     #: submitted routing matrix is preflighted against
     moe: dict | None = None
+    #: NPB FT field (kind == "ft"): ``{"shape", "alpha", "b_blocks",
+    #: "max_group"}``; the spectrum's planes are ``device_arrays["re"]`` /
+    #: ``["im"]``
+    ft: dict | None = None
     #: graph layout engagement (kind == "graph"): the split width (None
     #: when no node was split), the nodes split, the virtual rows and the
     #: padded slots a node step sweeps (:func:`repro.graphs.gen.split_stats`)
@@ -355,6 +361,40 @@ class KernelRegistry:
         op.plans = {"fft": plan_fft_stockham(
             n, batch=8, dtype=str(np.dtype(dtype))).raise_if_invalid()}
         op.device_arrays = {"wre": jnp.asarray(wre), "wim": jnp.asarray(wim)}
+        return self._admit(op, sw)
+
+    def register_ft(self, name: str, re, im, alpha: float
+                    ) -> RegisteredOperand:
+        """Admit an NPB FT field u = ``re`` + i ``im`` of shape (nx, ny, nz)
+        with diffusion constant ``alpha``: the forward 3-D transform runs
+        on the device once, here, and its spectrum stays resident; each
+        ``ft`` request evolves and inverts it for one time step.  The plan
+        (:func:`plan_ft`) fixes each pass's signal block and the widest
+        group of steps one launch may stack in HBM."""
+        import jax.numpy as jnp
+
+        from repro.kernels import fft as fft_k
+
+        sw = Stopwatch().start()
+        if self.n_devices > 1:
+            raise ValueError("ft has no sharded execution path; register "
+                             "it on a single-device registry")
+        dtype = np.dtype(float_dtype())
+        with obs_trace.phase("ft.register"):
+            re, im = np.asarray(re, dtype), np.asarray(im, dtype)
+            if re.ndim != 3 or re.shape != im.shape:
+                raise ValueError(f"ft field planes must be 3-D and of one "
+                                 f"shape, got {re.shape} and {im.shape}")
+            plan = plan_ft(re.shape, dtype=str(dtype)).raise_if_invalid()
+            op = RegisteredOperand(name=name, kind="ft", signature=None,
+                                   n=int(re.size))
+            op.ft = {"shape": re.shape, "alpha": float(alpha),
+                     "b_blocks": plan.b_blocks, "max_group": plan.max_group}
+            op.plans = {"ft": plan}
+            sre, sim = fft_k.ft_spectrum(jnp.asarray(re), jnp.asarray(im),
+                                         b_blocks=plan.b_blocks)
+            op.device_arrays = {"re": sre.block_until_ready(),
+                                "im": sim.block_until_ready()}
         return self._admit(op, sw)
 
     def register_moe(self, name: str, *, n_tokens: int, n_slots: int,

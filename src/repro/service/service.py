@@ -19,7 +19,10 @@ batched execution core:
   (damping, iters) configurations, as columns of one batched
   ``bfs_sell`` / ``pagerank_sell`` drive;
 * FFT requests of equal length stack into a single batched
-  ``fft_stockham`` call.
+  ``fft_stockham`` call;
+* NPB FT requests (a time step t of a registered field) stack as the
+  group axis of one ``ft_step`` program over the resident spectrum, as
+  many a launch as the plan lets HBM hold.
 
 ``max_queue`` bounds the admission queue: a full queue rejects the submit
 with :class:`QueueFull` (counted in ``stats["rejected"]``) instead of
@@ -39,6 +42,9 @@ loop), ``svc.preflight`` (each submit), and per launch ``svc.prepare``
 (validate, cast, stack, pad, device put), ``svc.launch`` (the kernel
 call; the graph level loop inside it), ``svc.fetch`` (the device wait
 and copy back) and ``svc.split`` (results to the group's requests).
+The NPB FT step is one program: its parts, ``ft.evolve``, ``ft.pass``
+(per ``axis``) and ``ft.sample``, are scopes of that program
+(:func:`repro.obs.trace.scope`), on its device operations.
 """
 from __future__ import annotations
 
@@ -62,6 +68,7 @@ from repro.obs import trace as obs_trace
 from repro.analysis.preflight import (
     plan_bfs_sell,
     plan_fft_stockham,
+    plan_ft,
     plan_moe_dispatch,
     plan_pagerank_sell,
     plan_spmm_sell,
@@ -73,7 +80,7 @@ from repro.service.registry import KernelRegistry, RegisteredOperand
 from repro.serve.slots import SlotLoop
 from repro.sparse.formats import pow2_ceil, widest_k_tile
 
-OPS = ("spmv", "bfs", "pagerank", "fft", "moe_dispatch")
+OPS = ("spmv", "bfs", "pagerank", "fft", "moe_dispatch", "ft")
 
 #: request class of each op for the per-class latency histograms:
 #: ``moe_dispatch`` is LM dispatch traffic, everything else is plain kernel
@@ -106,6 +113,8 @@ STATS_KEYS = (
     "moe_dispatch_launches",  # batched MoE combine launches (LM serving)
     "graph_steps",          # BFS levels / PageRank power steps launched
     "graph_slots",          # padded slots x state columns those steps swept
+    "ft_steps",             # NPB FT time steps served
+    "ft_axis_passes",       # batched 1-D FFT passes of those launches
 )
 
 
@@ -442,6 +451,12 @@ class KernelService(SlotLoop[KernelRequest]):
             plans["fft"] = plan_fft_stockham(
                 record.n, batch=8,
                 dtype=str(record.device_arrays["wre"].dtype))
+        elif record.kind == "ft":
+            ft = record.ft
+            plans["ft"] = plan_ft(
+                ft["shape"], k=max(1, min(ft["max_group"],
+                                          pow2_ceil(self.n_slots))),
+                dtype=str(record.device_arrays["re"].dtype))
         elif record.kind == "moe" and record.slab_meta is not None:
             m = record.moe
             plans["moe_dispatch"] = plan_moe_dispatch(
@@ -776,7 +791,9 @@ class KernelService(SlotLoop[KernelRequest]):
 
     def _run_fft(self, operand, reqs):
         """True micro-batch: stack every request's signal rows into one
-        batched Stockham call against the operand's precomputed twiddles."""
+        batched Stockham call against the operand's precomputed twiddles.
+        A payload is a real signal batch, or a tuple ``(re, im)`` of the
+        split planes of a complex one."""
         from repro.kernels import fft as fft_k
 
         if operand.kind != "fft":
@@ -787,41 +804,100 @@ class KernelService(SlotLoop[KernelRequest]):
         dtype = operand.device_arrays["wre"].dtype
 
         def check(req):
-            if np.iscomplexobj(req.payload):
+            split = isinstance(req.payload, tuple)
+            planes = req.payload if split else (req.payload,)
+            if split and len(planes) != 2:
+                raise ValueError(f"split planes are a tuple (re, im), got "
+                                 f"{len(planes)} items")
+            if any(np.iscomplexobj(p) for p in planes):
                 # a real-dtype cast would silently drop the imaginary plane
                 raise TypeError("complex signals are not supported; "
-                                "pass split re/im planes")
-            sig = np.atleast_2d(np.asarray(req.payload, dtype))
-            if sig.ndim != 2:
+                                "pass split re/im planes as a tuple (re, im)")
+            re = np.atleast_2d(np.asarray(planes[0], dtype))
+            im = (np.atleast_2d(np.asarray(planes[1], dtype)) if split
+                  else np.zeros_like(re))
+            if re.shape != im.shape:
+                raise ValueError(f"re and im planes differ in shape: "
+                                 f"{re.shape} != {im.shape}")
+            if re.ndim != 2:
                 raise ValueError(f"signal must be 1-D or 2-D (batch, n), "
-                                 f"got shape {sig.shape}")
-            if sig.shape[0] == 0:
+                                 f"got shape {re.shape}")
+            if re.shape[0] == 0:
                 raise ValueError("empty signal batch (0 rows)")
-            if sig.shape[-1] != n:
-                raise ValueError(f"signal length {sig.shape[-1]} != "
+            if re.shape[-1] != n:
+                raise ValueError(f"signal length {re.shape[-1]} != "
                                  f"registered fft length {n}")
-            return sig
+            return re, im
 
         with obs_trace.phase("svc.prepare"):
             good, sigs = self._validated(reqs, check)
             if not good:
                 return
-            rows, spans = [], []
-            for sig in sigs:
-                spans.append((len(rows), len(rows) + sig.shape[0]))
-                rows.extend(sig)
-            batch = jnp.asarray(np.stack(rows))
+            spans, lo = [], 0
+            for re, _ in sigs:
+                spans.append((lo, lo + re.shape[0]))
+                lo += re.shape[0]
+            batch_re = jnp.asarray(np.concatenate([r for r, _ in sigs]))
+            batch_im = jnp.asarray(np.concatenate([i for _, i in sigs]))
         sw = Stopwatch().start()
         with obs_trace.phase("svc.launch"):
             out = fft_k.fft_stockham(
-                batch, jnp.zeros_like(batch),
+                batch_re, batch_im,
                 operand.device_arrays["wre"], operand.device_arrays["wim"],
-                b_block=min(8, batch.shape[0]), interpret=self.interpret,
+                b_block=min(8, batch_re.shape[0]), interpret=self.interpret,
             )
         re, im = self._fetched(operand, "fft", out, sw)
         with obs_trace.phase("svc.split"):
             for req, (lo, hi) in zip(good, spans):
                 req.result = (re[lo:hi], im[lo:hi])
+
+    def _run_ft(self, operand, reqs):
+        """Each request is one NPB FT time step t >= 0 (the payload, an int)
+        of the operand's resident spectrum; its result is a (1025,) complex
+        array, u_t at the 1024 checksum points and last the checksum
+        (:func:`repro.kernels.fft.ft_step`).  A group's steps stack as the
+        program's group axis, at most the plan's ``max_group`` a launch,
+        each launch padded to a power of two."""
+        from repro.kernels import fft as fft_k
+
+        if operand.kind != "ft":
+            raise TypeError(f"operand {operand.name!r} is not an ft field")
+        import jax.numpy as jnp
+
+        ft, arrs = operand.ft, operand.device_arrays
+
+        def check(req):
+            t = req.payload
+            if isinstance(t, (bool, np.bool_)) or not isinstance(
+                    t, (int, np.integer)):
+                raise TypeError(f"ft payload must be an int time step, got "
+                                f"{type(t).__name__}")
+            if t < 0:
+                raise ValueError(f"ft time step must be >= 0, got {t}")
+            return int(t)
+
+        with obs_trace.phase("svc.prepare"):
+            good, steps = self._validated(reqs, check)
+        width = max(1, ft["max_group"])
+        for lo in range(0, len(good), width):
+            part = good[lo:lo + width]
+            with obs_trace.phase("svc.prepare"):
+                # cast on the host: jnp.asarray(x, dtype) of a float64
+                # array runs a device program of its own
+                decay = jnp.asarray(fft_k.ft_decay(
+                    ft["alpha"], _pow2_pad(steps[lo:lo + width])
+                ).astype(arrs["re"].dtype))
+            sw = Stopwatch().start()
+            with obs_trace.phase("svc.launch"):
+                out = fft_k.ft_step(arrs["re"], arrs["im"], decay,
+                                    b_blocks=ft["b_blocks"],
+                                    interpret=self.interpret)
+            out = self._fetched(operand, "ft", out, sw)
+            self.stats["ft_steps"] += len(part)
+            self.stats["ft_axis_passes"] += fft_k.FT_AXES
+            with obs_trace.phase("svc.split"):
+                for i, req in enumerate(part):
+                    req.result = out[i]
 
     def _run_moe_dispatch(self, operand, reqs):
         """The whole group is ONE batched combine SpMM: each request's

@@ -428,6 +428,41 @@ def test_service_rejects_complex_fft_payload(small_world):
         svc.poll(rid)
 
 
+@pytest.mark.parametrize("rows", [None, 3], ids=["1-D", "batch3"])
+def test_service_fft_takes_split_planes_as_one_complex_signal(small_world,
+                                                             rows):
+    """A tuple (re, im) is one complex signal's planes, coalesced with a
+    real signal as before."""
+    csr, graph = small_world
+    svc = KernelService(make_registry(csr, graph), n_slots=4)
+    shape = (128,) if rows is None else (rows, 128)
+    re, im = RNG.standard_normal(shape), RNG.standard_normal(shape)
+    real = RNG.standard_normal((2, 128))
+    split = svc.submit("fft", "fft", (re, im))
+    plain = svc.submit("fft", "fft", real)
+    svc.drain()
+    got_re, got_im = svc.poll(split)
+    want = np.atleast_2d(np.fft.fft(re + 1j * im, axis=-1))
+    assert got_re.shape == want.shape
+    np.testing.assert_allclose(got_re, want.real, atol=1e-8)
+    np.testing.assert_allclose(got_im, want.imag, atol=1e-8)
+    got_re, got_im = svc.poll(plain)
+    want = np.fft.fft(real, axis=-1)
+    np.testing.assert_allclose(got_re, want.real, atol=1e-8)
+    np.testing.assert_allclose(got_im, want.imag, atol=1e-8)
+    assert svc.stats["launches"] == 1
+
+
+def test_service_refuses_mismatched_split_planes(small_world):
+    csr, graph = small_world
+    svc = KernelService(make_registry(csr, graph), n_slots=2)
+    rid = svc.submit("fft", "fft", (RNG.standard_normal(128),
+                                    RNG.standard_normal((2, 128))))
+    svc.drain()
+    with pytest.raises(RuntimeError, match="differ in shape"):
+        svc.poll(rid)
+
+
 def test_service_release_drops_delivered_results(small_world):
     csr, graph = small_world
     svc = KernelService(make_registry(csr, graph), n_slots=2)

@@ -4,11 +4,12 @@ JAX describes a ``v5e:2x2`` topology and the TPU compiler, which is
 installed with JAX, compiles each kernel for it: what Mosaic refuses
 (gathers it cannot lower, blocks off the (8, 128) tiling, more VMEM than
 the scoped limit) fails here, at no chip time.  Shapes are the ones
-``chip_smoke.py`` serves: the CAGE10-like SpMV at k = 1 and k = 8, the
+``chip_smoke.py`` and the benchmark serve: the CAGE10-like SpMV at k = 1 and k = 8, the
 million-row streamed SpMM, BFS and PageRank steps on an R-MAT graph, the
-Stockham FFT, the MoE combine at Mixtral-8x7B widths, and the row-sharded
-SpMM on a four-device mesh.  Nothing runs: each test only checks that the
-compiled program holds the kernel (``tpu_custom_call``).
+Stockham FFT, NPB FT class C's 512^3 transforms, the MoE combine at
+Mixtral-8x7B widths, and the row-sharded SpMM on a four-device mesh.
+Nothing runs: each test only checks that the compiled program holds the
+kernel (``tpu_custom_call``).
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU library, and every
@@ -184,6 +185,32 @@ def test_fft_compiles(topo, one_chip, quiet_cache, n, batch):
         lowered = fft_k.fft_stockham.lower(
             sig, sig, tw, tw, b_block=8, interpret=False)
     _assert_kernel(lowered)
+
+
+@pytest.mark.parametrize("program", ["spectrum", "step"])
+def test_npb_ft_class_c_compiles(topo, one_chip, quiet_cache, program):
+    """NPB FT class C at its 512^3 grid, on the plan's blocks: the forward
+    transform of registration and the served step, each of whose
+    temporaries (XLA's count) fits the 16 GB chip beside the resident
+    spectrum."""
+    from repro.analysis.preflight import HBM_BUDGET_BYTES, plan_ft
+
+    plan = plan_ft((512, 512, 512), dtype="float32")
+    field = 2 * 4 * 512 ** 3
+    with jax.enable_x64(False):
+        if program == "spectrum":
+            plane = _spec((512, 512, 512), jnp.float32, one_chip)
+            lowered = fft_k.ft_spectrum.lower(
+                plane, plane, b_blocks=plan.b_blocks, interpret=False)
+        else:
+            plane = _spec((4, 512, 512, 128), jnp.float32, one_chip)
+            lowered = fft_k.ft_step.lower(
+                plane, plane, _spec((1,), jnp.float32, one_chip),
+                b_blocks=plan.b_blocks, interpret=False)
+        compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert field + temp <= plan.hbm_bytes <= HBM_BUDGET_BYTES
 
 
 def test_sharded_spmm_compiles_on_four_chips(topo, quiet_cache, cage10):
